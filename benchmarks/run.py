@@ -41,6 +41,10 @@ def main() -> None:
     if args.full and args.smoke:
         ap.error("--full and --smoke are mutually exclusive")
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
+
     from . import (
         accuracy,
         batch_bias,

@@ -38,8 +38,6 @@ JIT_ENTRY = {
     "jax.jit",
     "jax.pmap",
     "jax.shard_map",
-    "jax.experimental.shard_map.shard_map",
-    "repro.core.sharding.shard_map",
     "repro.core.sharding.shard_map_rows",
     "jax.experimental.pallas.pallas_call",
 }

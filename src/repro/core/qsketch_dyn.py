@@ -62,18 +62,35 @@ def _choose_and_quantize(cfg: SketchConfig, lo, hi, w):
     return j, y.astype(jnp.int32)
 
 
+def fold_sum(x):
+    """Sum over the last axis (a power of two) in one fixed order: add the
+    upper half onto the lower half until one lane is left.
+
+    XLA leaves the association order of a reduction to the backend, and a
+    Pallas kernel reduces in its own order, so two routes that must agree
+    bit for bit (``kernels/dyn_array_update.py``) both sum in this order:
+    the kernel's lane butterfly (``lane_fold_sum``) leaves exactly this tree
+    in lane 0.
+    """
+    n = x.shape[-1]
+    while n > 1:
+        n //= 2
+        x = x[..., :n] + x[..., n : 2 * n]
+    return x[..., 0]
+
+
 def _q_update_prob(cfg: SketchConfig, hist, w):
     """q_R for weight(s) w given histogram T (paper §4.3, O(2^b)).
 
     Untouched registers (still r_min) are intentionally absent from T: their
     e^{-w 2^{-(r_min+1)}} term is ~0 (Alg. 3 inits T to zeros), so
     q_R = 1 - (1/m) Σ_k T[k] e^{-w s_k} automatically treats them as
-    always-updatable.
+    always-updatable. The bin sum runs in ``fold_sum`` order.
     """
     s = jnp.asarray(estimators._bin_scales(cfg))  # 2^{-(k+r_min+1)}
     w = jnp.asarray(w, jnp.float32)
     expo = jnp.exp(-w[..., None] * s)  # (..., 2^b)
-    q = 1.0 - (hist.astype(jnp.float32) * expo).sum(-1) / cfg.m
+    q = 1.0 - fold_sum(hist.astype(jnp.float32) * expo) / cfg.m
     return jnp.maximum(q, _QR_FLOOR)
 
 

@@ -144,7 +144,7 @@ def _estimate_with_ci(cfg: SketchConfig, mesh, axis: str, regs, *, solver: str =
             cfg, SketchArrayState(regs=regs_l), solver=solver
         )
 
-    # check_rep=False on the newton path only: its lax.while_loop has no
+    # check_vma=False on the newton path only: its lax.while_loop has no
     # replication rule on current JAX (everything here is shard-local so the
     # check is vacuous). The lut solver is while_loop-free, so it keeps the
     # replication check on.
@@ -154,7 +154,7 @@ def _estimate_with_ci(cfg: SketchConfig, mesh, axis: str, regs, *, solver: str =
         in_dims=(0,),
         out_dims=(0, 0, 0),
         axis=axis,
-        check_rep=(solver == "lut"),
+        check_vma=(solver == "lut"),
     )(regs)
 
 

@@ -144,13 +144,13 @@ def _estimate_mle(cfg: SketchConfig, mesh, axis: str, regs, hists, *, solver: st
             return estimation.estimate_hists(cfg, full, kind="routed", solver="lut")
         return dyn_array.estimate_mle_rows(cfg, regs_l, solver=solver)
 
-    # check_rep=False on the newton path only: the MLE Newton is a
+    # check_vma=False on the newton path only: the MLE Newton is a
     # lax.while_loop (no replication rule); the solve is shard-local so the
     # check is vacuous. The lut solver is while_loop-free and reads the
     # maintained histograms — replication check stays on.
     return sharding.shard_map_rows(
         local, mesh, in_dims=(0, 0), out_dims=0, axis=axis,
-        check_rep=(solver == "lut"),
+        check_vma=(solver == "lut"),
     )(regs, hists)
 
 
@@ -181,7 +181,7 @@ def _merge(cfg: SketchConfig, mesh, axis: str, a, b):
             in_dims=(DynArrayState(0, 0, 0), DynArrayState(0, 0, 0)),
             out_dims=(0, 0, 0),
             axis=axis,
-            check_rep=False,  # MLE while_loop inside
+            check_vma=False,  # MLE while_loop inside
         )(DynArrayState(*a), DynArrayState(*b))
     )
 

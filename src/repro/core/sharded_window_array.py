@@ -158,7 +158,7 @@ def _rotate_impl(cfg: SketchConfig, mesh, axis: str, state):
         in_dims=(_ARRAY_DIMS, None, None, None),
         out_dims=_ARRAY_DIMS + (None, None, None),
         axis=axis,
-        check_rep=False,  # union-MLE re-base is a lax.while_loop
+        check_vma=False,  # union-MLE re-base is a lax.while_loop
     )(tuple(state)[:6], state.head, state.filled, state.epoch_id)
 
 
@@ -197,12 +197,12 @@ def _estimate_subring(cfg: SketchConfig, mesh, axis: str, w: int, regs, head, *,
             cfg, window_array.window_union_regs(st, w), solver=solver
         )
 
-    # check_rep stays off for newton (lax.while_loop, no replication rule)
+    # check_vma stays off for newton (lax.while_loop, no replication rule)
     # and fused (pallas_call, same); lut is while_loop-free so it keeps the
     # replication check on.
     return sharding.shard_map_rows(
         local, mesh, in_dims=(1, None), out_dims=0, axis=axis,
-        check_rep=(solver == "lut"),
+        check_vma=(solver == "lut"),
     )(regs, head)
 
 
@@ -213,7 +213,7 @@ def _estimate_full_ring(cfg: SketchConfig, mesh, axis: str, union_hists, *, solv
 
     return sharding.shard_map_rows(
         local, mesh, in_dims=(0,), out_dims=0, axis=axis,
-        check_rep=(solver == "lut"),
+        check_vma=(solver == "lut"),
     )(union_hists)
 
 
@@ -286,7 +286,7 @@ def _merge(cfg: SketchConfig, mesh, axis: str, regs_a, regs_b):
         in_dims=(1, 1),
         out_dims=_ARRAY_DIMS,
         axis=axis,
-        check_rep=False,  # MLE while_loop in the chat re-estimates
+        check_vma=False,  # MLE while_loop in the chat re-estimates
     )(regs_a, regs_b)
 
 

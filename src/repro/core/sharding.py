@@ -41,12 +41,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# jax.shard_map only exists on newer JAX; fall back to the experimental home.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map
-
 AXIS = "sketch"
 
 
@@ -120,7 +114,7 @@ def shard_map_rows(
     in_dims,
     out_dims,
     axis: str = AXIS,
-    check_rep: bool = True,
+    check_vma: bool = True,
 ):
     """Wrap a shard-local ``fn`` over row-sharded pytrees.
 
@@ -129,19 +123,19 @@ def shard_map_rows(
     receives each sharded leaf as a plain array of K/S rows and each
     replicated leaf whole, and must return outputs matching ``out_dims``.
 
-    ``check_rep=False`` is needed whenever the local body contains a
+    ``check_vma=False`` is needed whenever the local body contains a
     ``lax.while_loop`` (the Newton/MLE solvers have no replication rule on
     current JAX); everything these containers run locally is shard-local,
     so the check is vacuous there.
     """
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=tuple(tree_specs(d, axis) for d in in_dims),
         out_specs=tuple(tree_specs(d, axis) for d in out_dims)
         if isinstance(out_dims, tuple)
         else tree_specs(out_dims, axis),
-        check_rep=check_rep,
+        check_vma=check_vma,
     )
 
 

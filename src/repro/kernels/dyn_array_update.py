@@ -21,7 +21,10 @@ q_R + core tail and is bit-identical to ``core.dyn_array.update_batch``.
 Layout: histogram bins (NB = 2^b <= 256) on the lane axis padded to a
 128-multiple (zero-count pad bins contribute exact 0.0 to the sum); batch on
 sublanes. Padding batch rows carry w = 1 against a zero histogram row
-(q = 1) and are sliced off by the wrapper.
+(q = 1) and are sliced off by the wrapper. The bin sum follows
+``qsketch_dyn.fold_sum``'s order (``lane_fold_sum``), so q_R — and the
+chats built from it — match the jnp route bit for bit on the TPU too, where
+Mosaic's and XLA's own reductions associate differently.
 """
 
 from __future__ import annotations
@@ -31,9 +34,23 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from . import compat
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_B = 512
+
+
+def lane_fold_sum(x):
+    """Row sums of ``x`` (rows, NB), NB a multiple of 128, in
+    ``qsketch_dyn.fold_sum`` order: 128-lane groups fold pairwise, then a
+    lane butterfly (lane i += lane i + s, s = 64 .. 1) leaves that order's
+    sum in lane 0. Returns (rows, 1). Zero pad lanes beyond the 2^b real
+    bins add exact zeros, so the sum equals the unpadded fold."""
+    while x.shape[1] > 128:
+        h = x.shape[1] // 2
+        x = x[:, :h] + x[:, h:]
+    for s in (64, 32, 16, 8, 4, 2, 1):
+        x = x + pltpu.roll(x, 128 - s, 1)
+    return x[:, 0:1]
 
 
 def _keyed_qr_kernel(w_ref, hist_rows_ref, scales_ref, out_ref, *, m):
@@ -41,7 +58,7 @@ def _keyed_qr_kernel(w_ref, hist_rows_ref, scales_ref, out_ref, *, m):
     t = hist_rows_ref[...]  # (B_blk, NB) — this block's gathered rows
     s = scales_ref[...]  # (1, NB)
     expo = jnp.exp(-w * s)  # (B_blk, NB) lives only in VMEM/VREGs
-    acc = jnp.sum(t * expo, axis=1, keepdims=True)  # (B_blk, 1)
+    acc = lane_fold_sum(t * expo)  # (B_blk, 1), the jnp route's sum order
     out_ref[...] = 1.0 - acc / m
 
 
@@ -65,6 +82,6 @@ def dyn_array_qr_padded(
         ],
         out_specs=pl.BlockSpec((block_b, 1), lambda bi: (bi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, 1), jnp.float32),
-        compiler_params=compat.CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(weights, hist_rows, scales)

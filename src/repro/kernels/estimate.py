@@ -8,7 +8,7 @@ both stages on the resident tile:
 
   grid = (K_pad / block_k,), blocks independent ("parallel"): each step
   bincounts its (block_k × m_pad) int8 tile into a VMEM scratch histogram —
-  the window_union idiom, a fori_loop of masked lane reductions — then runs
+  ``window_union.bincount_rows``, masked lane reductions — then runs
   the rebased safeguarded Newton of ``estimators.qsketch_mle`` on the
   (block_k × 2^b) scratch, vectorized across the block's rows, for a FIXED
   ``_N_ITERS`` iterations (kernels cannot data-dependently early-exit a
@@ -35,29 +35,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import compat
+from .window_union import bincount_rows
+
 
 DEFAULT_BLOCK_K = 256
 _N_ITERS = 30
 _EPS_Z = 1e-4  # series-switch threshold for z = C*s (estimators._EPS_Z)
 
 
+def _expm1(z):
+    """exp(z) - 1 to a few ulps from exp and log alone (Kahan's form; Mosaic
+    has no expm1): the rounding of u = exp(z) cancels in (u - 1) * z / ln u."""
+    u = jnp.exp(z)
+    d = u - 1.0
+    return jnp.where(d == 0.0, z, d * z / jnp.log(u))
+
+
+def _two_sinh(x):
+    """2·sinh(x) without cancellation at small x (Mosaic has no sinh)."""
+    return _expm1(x) - _expm1(-x)
+
+
 def _estimate_kernel(
-    regs_ref, chat_ref, std_ref, conv_ref, hist_ref, *, m, nb_padded, r_min, top_bin
+    regs_ref, chat_ref, std_ref, conv_ref, hist_ref, *, m, r_min, top_bin
 ):
-    u = regs_ref[...].astype(jnp.int32)  # (block_k, m_pad)
-    lane_valid = jax.lax.broadcasted_iota(jnp.int32, u.shape, 1) < m
-
-    def bin_body(v, _):
-        cnt = jnp.sum(
-            jnp.where(lane_valid & (u == v + r_min), 1.0, 0.0),
-            axis=1,
-            keepdims=True,
-        )
-        hist_ref[:, pl.ds(v, 1)] = cnt.astype(jnp.float32)
-        return _
-
-    jax.lax.fori_loop(0, nb_padded, bin_body, None)
+    bincount_rows(hist_ref, regs_ref[...].astype(jnp.int32), m=m, r_min=r_min)
 
     t = hist_ref[...]  # (block_k, nb_pad) f32, rows sum to m
     lane = jax.lax.broadcasted_iota(jnp.int32, t.shape, 1)
@@ -83,9 +85,9 @@ def _estimate_kernel(
     def f_and_fprime(c):
         z = c * s
         zz = jnp.clip(z, _EPS_Z, 88.0)
-        f_int = jnp.where(z < _EPS_Z, 1.0 / c - 0.5 * s, s / jnp.expm1(zz)) - s
+        f_int = jnp.where(z < _EPS_Z, 1.0 / c - 0.5 * s, s / _expm1(zz)) - s
         lsh = jnp.where(
-            zz > 40.0, zz / 2.0, jnp.log(2.0 * jnp.sinh(jnp.minimum(zz, 40.0) / 2.0))
+            zz > 40.0, zz / 2.0, jnp.log(_two_sinh(jnp.minimum(zz, 40.0) / 2.0))
         )
         fp_int = jnp.where(
             z < _EPS_Z, -1.0 / (c * c), -jnp.exp(2.0 * (jnp.log(s) - lsh))
@@ -93,9 +95,9 @@ def _estimate_kernel(
 
         za = c * a
         zza = jnp.clip(za, _EPS_Z, 88.0)
-        f_top = jnp.where(za < _EPS_Z, 1.0 / c - 0.5 * a, a / jnp.expm1(zza))
+        f_top = jnp.where(za < _EPS_Z, 1.0 / c - 0.5 * a, a / _expm1(zza))
         lsha = jnp.where(
-            zza > 40.0, zza / 2.0, jnp.log(2.0 * jnp.sinh(jnp.minimum(zza, 40.0) / 2.0))
+            zza > 40.0, zza / 2.0, jnp.log(_two_sinh(jnp.minimum(zza, 40.0) / 2.0))
         )
         fp_top = jnp.where(
             za < _EPS_Z, -1.0 / (c * c), -jnp.exp(2.0 * (jnp.log(a) - lsha))
@@ -153,9 +155,7 @@ def estimate_rows_padded(
     applies the kind convention.
     """
     kp, mp = regs.shape
-    kernel = functools.partial(
-        _estimate_kernel, m=m, nb_padded=nb_padded, r_min=r_min, top_bin=top_bin
-    )
+    kernel = functools.partial(_estimate_kernel, m=m, r_min=r_min, top_bin=top_bin)
     return pl.pallas_call(
         kernel,
         grid=(kp // block_k,),
@@ -171,6 +171,6 @@ def estimate_rows_padded(
             jax.ShapeDtypeStruct((kp, 1), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, nb_padded), jnp.float32)],
-        compiler_params=compat.CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(regs)

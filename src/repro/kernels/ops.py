@@ -41,6 +41,7 @@ from repro.core.types import (
     WindowArrayState,
 )
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 
 from . import (
     dyn_array_update,
@@ -53,6 +54,8 @@ from . import (
 )
 
 _NEG_INF = float(np.finfo(np.float32).min)
+# VMEM the keyed SketchArray kernel may hold resident (slabs + y tile).
+_SKETCH_ARRAY_VMEM_BUDGET = 6 * 2**20
 _POS_INF = float(np.finfo(np.float32).max)
 
 _M_KERNEL_TRACES = obs_metrics.counter(
@@ -72,7 +75,7 @@ def _note_trace(op: str) -> None:
     mutation during tracing captures no tracer, so the jitted computation
     is untouched.
     """
-    if obs_metrics.enabled() and not jax.core.trace_state_clean():
+    if obs_metrics.enabled() and obs_trace.tracing_active():
         _M_KERNEL_TRACES.labels(op=op).inc()
 
 
@@ -159,7 +162,8 @@ def sketch_array_update_op(
     ``mask`` is folded into log2w (masked rows -> -inf -> y = r_min), which is
     exactly the core's post-clip masking, so bit-identity is preserved.
     The register slab (K_pad x block_m, int32) must sit in VMEM next to the
-    y tile; block_m is halved until the slab fits a ~6 MiB budget.
+    y tile; block_m is halved until the slab fits a 6 MiB budget, and a K
+    whose slab does not fit even at block_m = 128 raises ValueError.
     """
     _note_trace("sketch_array_update")
     interpret = _interpret_default() if interpret is None else interpret
@@ -170,11 +174,20 @@ def sketch_array_update_op(
     bb = block_b or min(sketch_array_update.DEFAULT_BLOCK_B, _round_up(b, 8))
     bm = block_m or min(sketch_array_update.DEFAULT_BLOCK_M, _round_up(cfg.m, 128))
     kp = _round_up(k, 8)
+    # Residency = regs_ref + out_ref slabs (int32 each) + the y tile.
+    resident = lambda bm: (2 * kp + bb) * bm * 4
     if block_m is None:
         # Halve in 128-aligned steps: M_blk must stay a lane-tile multiple.
-        # Residency = regs_ref + out_ref slabs (int32 each) + the y tile.
-        while (2 * kp + bb) * bm * 4 > 6 * 2**20 and bm > 128:
+        while resident(bm) > _SKETCH_ARRAY_VMEM_BUDGET and bm > 128:
             bm = max(128, (bm // 2) // 128 * 128)
+    if resident(bm) > _SKETCH_ARRAY_VMEM_BUDGET:
+        raise ValueError(
+            f"sketch_array_update_op: K={k} rows need {resident(bm)} B of VMEM "
+            f"for the register slab at block_m={bm}, over the "
+            f"{_SKETCH_ARRAY_VMEM_BUDGET} B budget (K <= "
+            f"{(_SKETCH_ARRAY_VMEM_BUDGET // (128 * 4) - bb) // 2} fits); use "
+            "core.sketch_array.update for larger K"
+        )
     bp, mp = _round_up(b, bb), _round_up(cfg.m, bm)
 
     log2w = jnp.log2(weights.astype(jnp.float32))
@@ -442,9 +455,9 @@ def window_union_estimate_op(
     )
     # Epoch slot ei is inside the window iff its age (head - ei) mod E < w.
     age = (state.head - jnp.arange(e, dtype=jnp.int32)) % e
-    include = (age < w).astype(jnp.int32)[:, None]
+    include = (age < w).astype(jnp.int32)
 
-    _, hists = window_union.window_union_padded(
+    hists = window_union.window_union_padded(
         regs,
         include,
         m=m,
@@ -524,7 +537,7 @@ def sharded_dyn_array_update_op(
     data-dependent tail stays ``dyn_array._apply_update`` — run under
     ``shard_map`` with the replicated batch hash-routed to the owning shard
     (``sharding.own_slots``), the same dispatch as the jnp-backed sharded
-    path. ``check_rep=False`` because pallas_call has no replication rule;
+    path. ``check_vma=False`` because pallas_call has no replication rule;
     every operand the kernel touches is shard-local, so the check is
     vacuous.
     """
@@ -551,7 +564,7 @@ def sharded_dyn_array_update_op(
             in_dims=(DynArrayState(0, 0, 0), None, None, None, None),
             out_dims=(0, 0, 0),
             axis=axis,
-            check_rep=False,
+            check_vma=False,
         )(DynArrayState(*state), keys, ids, weights, mask)
     )
 
@@ -591,7 +604,7 @@ def sharded_window_union_estimate_op(
         )
 
     return sharding.shard_map_rows(
-        local, mesh, in_dims=(1, None), out_dims=0, axis=axis, check_rep=False
+        local, mesh, in_dims=(1, None), out_dims=0, axis=axis, check_vma=False
     )(state.regs, state.head)
 
 

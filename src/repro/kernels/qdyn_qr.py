@@ -19,7 +19,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from . import compat
+from jax.experimental.pallas import tpu as pltpu
+
+from .dyn_array_update import lane_fold_sum
 
 DEFAULT_BLOCK_B = 512
 
@@ -30,7 +32,7 @@ def _qr_kernel(w_ref, hist_ref, scales_ref, out_ref, *, m):
     s = scales_ref[...]  # (1, NB)
     # exp(-w * s): (B_blk, NB) lives only in VMEM/VREGs.
     expo = jnp.exp(-w * s)
-    acc = jnp.sum(t * expo, axis=1, keepdims=True)  # (B_blk, 1)
+    acc = lane_fold_sum(t * expo)  # (B_blk, 1), core's sum order
     out_ref[...] = 1.0 - acc / m
 
 
@@ -51,6 +53,6 @@ def qdyn_qr_padded(weights, hist, scales, *, m: int, block_b: int = DEFAULT_BLOC
         ],
         out_specs=pl.BlockSpec((block_b, 1), lambda bi: (bi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, 1), jnp.float32),
-        compiler_params=compat.CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(weights, hist, scales)

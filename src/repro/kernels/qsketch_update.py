@@ -29,7 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from . import compat
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import hashing
 
@@ -113,7 +113,7 @@ def qsketch_update_padded(
         ],
         out_specs=pl.BlockSpec((1, block_m), lambda mi, bi: (0, mi)),
         out_shape=jax.ShapeDtypeStruct((1, m), jnp.int32),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -174,7 +174,7 @@ def float_sketch_update_padded(
         ],
         out_specs=pl.BlockSpec((1, block_m), lambda mi, bi: (0, mi)),
         out_shape=jax.ShapeDtypeStruct((1, m), jnp.float32),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

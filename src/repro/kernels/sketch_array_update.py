@@ -30,7 +30,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from . import compat
+from jax.experimental.pallas import tpu as pltpu
 
 from .qsketch_update import _tile_y
 
@@ -115,7 +115,7 @@ def sketch_array_update_padded(
         ],
         out_specs=pl.BlockSpec((k, block_m), lambda mi, bi: (0, mi)),
         out_shape=jax.ShapeDtypeStruct((k, m), jnp.int32),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -27,10 +27,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import hashing
-
-from . import compat
 
 DEFAULT_BLOCK_B = 512
 
@@ -98,6 +97,6 @@ def virtual_pool_route_padded(
             jax.ShapeDtypeStruct((b, 1), jnp.int32),
             jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ],
-        compiler_params=compat.CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(lo, hi, t_lo, t_hi, log2w)

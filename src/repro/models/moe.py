@@ -27,12 +27,6 @@ import jax.numpy as jnp
 from . import common, sharding
 from .common import ParamDef
 
-# jax.shard_map only exists on newer JAX; fall back to the experimental home.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def defs(cfg):
     m = cfg.moe
@@ -193,7 +187,7 @@ def apply_a2a(params, x, cfg, mesh):
         )
         return y
 
-    y = _shard_map(
+    y = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(axes, None), P(), P("model", None, None), P("model", None, None), P("model", None, None)),

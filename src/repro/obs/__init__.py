@@ -1,7 +1,7 @@
 """qobs — host-side observability for the sketch stack (DESIGN.md §10).
 
 Four parts, all strictly OUTSIDE jit (no module here may touch a traced
-value — emissions are host Python, guarded by ``jax.core.trace_state_clean``
+value — emissions are host Python, guarded by ``obs.trace.tracing_active``
 wherever a caller might sit inside a traced region):
 
 * ``obs.metrics`` — a process-local registry of counters, gauges, and

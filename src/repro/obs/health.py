@@ -46,7 +46,6 @@ from __future__ import annotations
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import estimation, key_directory
@@ -180,7 +179,7 @@ def health_report(
     "threshold", "warn"}}, "warnings": [...], "ok": bool}``. Host-only —
     raises if called under an active jax trace.
     """
-    if not jax.core.trace_state_clean():
+    if trace.tracing_active():
         raise RuntimeError(
             "health_report is host-only (it syncs device values and runs "
             "solves) — never call it inside jit/shard_map"

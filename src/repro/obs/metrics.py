@@ -32,7 +32,7 @@ Semantics:
   fallback).
 * **Strictly outside jit.** Values are host Python numbers; handles must
   never receive traced values. Callers that may sit under a ``jax.jit``
-  trace guard emissions with ``jax.core.trace_state_clean()`` (the
+  trace guard emissions with ``obs.trace.tracing_active()`` (the
   monitor layer does this for you).
 """
 

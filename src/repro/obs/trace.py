@@ -11,7 +11,7 @@ Two rules keep tracing honest in an async-dispatch JAX program:
 
 * **Strictly outside jit.** A span inside a traced region would time the
   *trace*, not the run, and record exactly once. When tracing is enabled,
-  ``span`` checks ``jax.core.trace_state_clean()`` and degrades to a no-op
+  ``span`` checks ``tracing_active()`` and degrades to a no-op
   under any active trace — so host helpers that are occasionally called
   from jitted code stay safe.
 * **Host wall-time is not device time.** Dispatch returns before the
@@ -54,6 +54,13 @@ class _NullSpan:
 
 
 _NULL = _NullSpan()
+
+
+def tracing_active() -> bool:
+    """True while any jax transformation is tracing (jit, vmap,
+    shard_map, grad): the guard every host-side emission checks, so code
+    that is sometimes called from a traced region records nothing there."""
+    return not jax.core.trace_ctx.is_top_level()
 
 
 class _Span:
@@ -108,7 +115,7 @@ class Tracer:
     def span(self, name: str, **args):
         """Context manager timing one stage. No-op while disabled or while
         any jax trace is active (see module docstring)."""
-        if not self._enabled or not jax.core.trace_state_clean():
+        if not self._enabled or tracing_active():
             return _NULL
         return _Span(self, name, args)
 
@@ -120,7 +127,7 @@ class Tracer:
             not self._enabled
             or self.sync_every <= 0
             or tick % self.sync_every
-            or not jax.core.trace_state_clean()
+            or tracing_active()
         ):
             return False
         with self.span(name, sampled=True, tick=tick):

@@ -447,12 +447,14 @@ def _ticketed(update):
 
 
 @functools.lru_cache(maxsize=32)
-def _dyn_update_fn(cfg: SketchConfig, use_kernel: bool):
+def _dyn_update_fn(cfg: SketchConfig, use_kernel: bool, interpret: bool | None = None):
     if use_kernel:
         from repro.kernels import ops
 
         def upd(st, keys, ids, w, mask):
-            return ops.dyn_array_update_op(cfg, st, keys, ids, w, mask=mask)
+            return ops.dyn_array_update_op(
+                cfg, st, keys, ids, w, mask=mask, interpret=interpret
+            )
 
         return jax.jit(_ticketed(upd), donate_argnums=(0,))
 
@@ -471,16 +473,20 @@ def _dyn_update_fn(cfg: SketchConfig, use_kernel: bool):
 
 def dyn_pipeline(
     cfg: SketchConfig, state, icfg: IngestConfig = IngestConfig(),
-    *, use_kernel: bool = False, name: str | None = None,
+    *, use_kernel: bool = False, interpret: bool | None = None,
+    name: str | None = None,
 ) -> IngestPipeline:
     """Ingest front of a DynArray: donated fused keyed updates, no rotate.
 
     ``use_kernel=True`` routes the q_R stage through the Pallas kernel
-    (``kernels/ops.dyn_array_update_op``) inside the same donating jit.
+    (``kernels/ops.dyn_array_update_op``) inside the same donating jit;
+    ``interpret`` is passed to it (None: interpret off the TPU backend).
     The jitted update closure is cached per cfg, so pipelines over the
     same geometry share one compiled executable.
     """
-    return IngestPipeline(icfg, state, _dyn_update_fn(cfg, use_kernel), name=name)
+    return IngestPipeline(
+        icfg, state, _dyn_update_fn(cfg, use_kernel, interpret), name=name
+    )
 
 
 @functools.lru_cache(maxsize=32)

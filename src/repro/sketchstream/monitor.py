@@ -83,7 +83,6 @@ from __future__ import annotations
 
 from typing import Any, NamedTuple
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import (
@@ -114,6 +113,7 @@ from repro.core.types import (
 )
 from repro.core.virtual_dyn_array import VirtualConfig
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 
 # Declared tenant-telemetry families, labeled by monitor instance kind — the
 # five monitor classes (and the ingest-front TenantWindowIngest) publish
@@ -179,7 +179,7 @@ def publish_tenant_metrics(kind: str, values: dict) -> None:
     no-ops under any active jax trace: the registry then simply reflects
     the last host-side read.
     """
-    if not obs_metrics.enabled() or not jax.core.trace_state_clean():
+    if not obs_metrics.enabled() or obs_trace.tracing_active():
         return
     for name, v in values.items():
         fam = _TENANT_FAMILIES.get(name)

@@ -11,8 +11,16 @@ helpers, which is noise next to the solves themselves.
 
 import os
 
-import jax
-import pytest
+# The sharded and mesh tests want 8 host devices. XLA reads this flag once,
+# at the first backend initialisation, so it is appended here — before any
+# test module can touch a backend — keeping whatever the caller already set.
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = " ".join(
+        f for f in (os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=8") if f
+    )
+
+import jax  # noqa: E402  (after XLA_FLAGS)
+import pytest  # noqa: E402
 
 
 @pytest.fixture(autouse=True, scope="module")

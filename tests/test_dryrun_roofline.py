@@ -21,7 +21,8 @@ def test_hlo_stats_loop_aware():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.roofline import hlo_stats
-        mesh = jax.make_mesh((4,2), ('data','model'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ('data', 'model'))
         def make(n):
             def f(x, w):
                 def body(c, wi):
@@ -85,7 +86,8 @@ def test_dryrun_cell_smoke_mesh():
             'train_4k': dataclasses.replace(C.SHAPES['train_4k'], seq=64, batch=8),
             'decode_32k': dataclasses.replace(C.SHAPES['decode_32k'], seq=64, batch=8),
         })
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ('data', 'model'))
         for arch, shape in [('qwen3-8b', 'train_4k'), ('kimi-k2-1t-a32b', 'train_4k'),
                             ('whisper-large-v3', 'decode_32k')]:
             rec = dl.run_cell(arch, shape, mesh)

@@ -22,7 +22,8 @@ def test_moe_a2a_matches_scatter_8dev():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import dataclasses, jax, jax.numpy as jnp, numpy as np
         from repro.models import ModelConfig, LayerSpec, MoEConfig, moe, common
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ('data', 'model'))
         cfg = ModelConfig(name='t', n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
                           d_ff=128, vocab=128, pattern=(LayerSpec(ffn='moe'),),
                           moe=MoEConfig(num_experts=8, top_k=2, d_ff=32, capacity_factor=8.0),

@@ -1,32 +1,20 @@
 """Unit tests for the logical-axis -> mesh-axis resolver (no device mesh ops,
 just spec construction against 2- and 3-axis meshes)."""
 
-import jax
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.models import sharding as ms
 from repro.models.common import ParamDef
-
-
-def _abstract_mesh(sizes, names):
-    """AbstractMesh across the signature change: newer JAX takes
-    (axis_sizes, axis_names); 0.4.x takes ((name, size), ...) pairs."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
 
 
 @pytest.fixture(scope="module")
 def meshes():
     # Abstract meshes: no XLA device initialization issues on CPU (uses the
     # single real device repeated logically via AbstractMesh).
-    two = _abstract_mesh((16, 16), ("data", "model"))
-    three = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    two = AbstractMesh((16, 16), ("data", "model"))
+    three = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     return two, three
 
 

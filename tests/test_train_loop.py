@@ -108,18 +108,19 @@ os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={sys.argv[1]}
 import jax, jax.numpy as jnp, numpy as np
 from repro.models import common as mc, sharding as ms, transformer
 from repro.train import checkpoint, elastic
+from repro.launch.mesh import make_mesh
 from repro import configs
 cfg = configs.smoke_config('qwen3-8b')
 defs = transformer.model_defs(cfg)
 ck = sys.argv[2]
 if sys.argv[3] == 'save':
-    mesh = jax.make_mesh((int(sys.argv[1])//2, 2), ('data','model'))
+    mesh = make_mesh((int(sys.argv[1])//2, 2), ('data','model'))
     params = mc.init_params(defs, jax.random.PRNGKey(0))
     params = elastic.reshard_state(params, defs, mesh)
     checkpoint.save(ck, 1, params)
     print('SAVED', len(jax.tree.leaves(params)))
 else:
-    mesh = jax.make_mesh((int(sys.argv[1])//2, 2), ('data','model'))
+    mesh = make_mesh((int(sys.argv[1])//2, 2), ('data','model'))
     like = mc.init_params(defs, jax.random.PRNGKey(0))
     host, _ = checkpoint.restore(ck, 1, like)
     params = elastic.reshard_state(host, defs, mesh)
