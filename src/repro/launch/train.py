@@ -108,9 +108,6 @@ def main(argv=None):
     ap.add_argument("--obs-trace", default="",
                     help="record stage spans and save a Perfetto-loadable "
                          "Chrome trace JSON here at exit")
-    ap.add_argument("--obs-sync-every", type=int, default=0,
-                    help="sampled block_until_ready cadence for device-time "
-                         "attribution in the trace (0 = never sync)")
     ap.add_argument("--abort-after", type=int, default=0,
                     help="simulate preemption: stop after N steps this invocation (tests)")
     args = ap.parse_args(argv)
@@ -126,10 +123,8 @@ def main(argv=None):
     # Observability sinks (DESIGN.md §10): spans record only when a trace
     # path is requested; the metrics registry is always live (QOBS_DISABLED
     # turns it off) and the JSONL writer logs per-interval deltas.
-    if args.obs_trace or args.obs_sync_every:
-        obs_trace.configure(
-            enabled=bool(args.obs_trace), sync_every=args.obs_sync_every
-        )
+    if args.obs_trace:
+        obs_trace.configure(enabled=True)
     obs_jsonl = (
         obs_export.JsonlWriter(args.obs_jsonl, delta=True)
         if args.obs_jsonl else None

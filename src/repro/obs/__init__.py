@@ -8,10 +8,13 @@ wherever a caller might sit inside a traced region):
   log2-bucketed histograms (the paper's quantization idiom applied to
   telemetry) with namespaced snake_case names, per-series labels,
   delta/cumulative snapshots, and a no-op path when disabled.
-* ``obs.trace``   — span-based stage tracing (push/seal/dispatch/retire/
-  rotate/estimate/solve) with nesting via contextvars, Chrome trace-event
-  JSON export loadable in Perfetto, and a sampled ``block_until_ready``
-  hook so device wall-time is attributable without syncing every batch.
+* ``obs.trace``   — span-based stage tracing (route/push/seal/dispatch/
+  retire/rotate/estimate/solve) with nesting via contextvars and Chrome
+  trace-event JSON export loadable in Perfetto; while enabled each span is
+  also a ``jax.profiler.TraceAnnotation``, so it sits on the profiler's
+  host plane on the device trace's clock, and each XLA compile is
+  recorded (``jax/compile``, counted in ``jax_compiles``) with the span
+  that caused it.
 * ``obs.health``  — sketch self-introspection over every container state
   (top-bin saturation, histogram occupancy, union-cache staleness,
   directory load, anytime-vs-MLE drift, CI width) behind one

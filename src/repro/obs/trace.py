@@ -1,11 +1,32 @@
-"""Span-based stage tracing with Chrome trace-event / Perfetto export.
+"""Span-based stage tracing on the profiler's clock, with Chrome trace-event /
+Perfetto export.
 
-Spans mark host-side pipeline stages (push/seal/dispatch/retire/rotate/
-estimate/solve). Each ``span(name)`` context manager records one Chrome
-"complete" event (``ph: "X"``) with microsecond start/duration; nesting is
-tracked via ``contextvars`` so a span opened inside another carries its
-full ``path`` in the event args and renders nested in Perfetto (load the
-saved JSON at https://ui.perfetto.dev or chrome://tracing).
+Spans mark host-side pipeline stages (route/push/seal/dispatch/retire/
+rotate/estimate/solve). Each ``span(name)`` context manager records one
+Chrome "complete" event (``ph: "X"``) with microsecond start/duration;
+nesting is tracked via ``contextvars`` so a span opened inside another
+carries its full ``path`` in the event args and renders nested in Perfetto
+(load the saved JSON at https://ui.perfetto.dev or chrome://tracing).
+
+While the tracer is enabled, each span is also a
+``jax.profiler.TraceAnnotation`` of the same name: while the JAX profiler
+runs (``jax.profiler.start_trace``), the span sits on the host plane of
+its ``.xplane.pb``, on the same clock as the device's operations, so xprof
+or Perfetto shows each host stage beside the device work it launched or
+waited for.
+
+Two more kinds of event land in the same list, neither a span:
+
+* ``record(name, t0_ns)`` — an interval that crosses calls (the ingest
+  staging fill, from the first element entering a buffer to its seal):
+  an event only, with no profiler annotation;
+* ``jax/compile`` — one per XLA backend compile or persistent-cache load
+  while the default tracer is enabled, with the compiled function's name
+  (``fun``) and the enclosing span ``path``, from a ``jax.monitoring``
+  listener registered on the first ``configure(enabled=True)``. The same
+  compile increments the registry counter ``jax_compiles{span, fun}``. In
+  a warmed-up loop the count stays at 0; a compile there is a shape the
+  warm-up missed, named by the step that caused it.
 
 Two rules keep tracing honest in an async-dispatch JAX program:
 
@@ -15,15 +36,15 @@ Two rules keep tracing honest in an async-dispatch JAX program:
   under any active trace — so host helpers that are occasionally called
   from jitted code stay safe.
 * **Host wall-time is not device time.** Dispatch returns before the
-  device finishes, so a "dispatch" span measures enqueue cost only. The
-  sampled sync hook (``maybe_sync``) closes the gap: every
-  ``sync_every``-th tick it runs ``jax.block_until_ready`` under its own
-  span, attributing accumulated device time to that point WITHOUT paying a
-  pipeline-draining sync on every batch (the tradeoff is documented in
-  DESIGN.md §10 — the sampled batch itself loses its overlap).
+  device finishes, so a "dispatch" span measures enqueue cost only, and a
+  span around a host read of a device value measures the wait for
+  everything queued on the device ahead of it. Device time itself is read
+  from the profiler's device planes, which the annotations share a clock
+  with; no span syncs the device.
 
 Disabled (the default), ``span`` returns a shared no-op context manager:
-one function call + one branch per instrumentation point.
+one function call + one branch per instrumentation point, with no
+annotation built and no clock read.
 """
 
 from __future__ import annotations
@@ -34,10 +55,25 @@ import threading
 import time
 
 import jax
+from jax.profiler import TraceAnnotation
+
+from repro.obs import metrics as obs_metrics
 
 # Nesting stack of span names for the current (context-local) execution.
 _STACK: contextvars.ContextVar[tuple] = contextvars.ContextVar(
     "qobs_span_stack", default=()
+)
+
+# The jax.monitoring duration event of one backend compile (or persistent
+# cache load): ``jax._src.dispatch.BACKEND_COMPILE_EVENT`` in JAX 0.9.
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# The tracer event recorded for each such compile.
+COMPILE_EVENT = "jax/compile"
+
+_M_COMPILES = obs_metrics.counter(
+    "jax_compiles",
+    "XLA compiles and persistent-cache loads while tracing is enabled",
+    labels=("span", "fun"),
 )
 
 
@@ -64,9 +100,10 @@ def tracing_active() -> bool:
 
 
 class _Span:
-    """One live span: records a Chrome 'X' event on exit."""
+    """One live span: a profiler annotation while open, and a Chrome 'X'
+    event on exit."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0", "_token")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_token", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
@@ -75,11 +112,14 @@ class _Span:
 
     def __enter__(self):
         self._token = _STACK.set(_STACK.get() + (self.name,))
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         dur_ns = time.perf_counter_ns() - self._t0
+        self._ann.__exit__(*exc)
         stack = _STACK.get()
         _STACK.reset(self._token)
         self._tracer._record(
@@ -91,9 +131,8 @@ class _Span:
 class Tracer:
     """A span recorder: configuration + the accumulated event list."""
 
-    def __init__(self, enabled: bool = False, sync_every: int = 0):
+    def __init__(self, enabled: bool = False):
         self._enabled = bool(enabled)
-        self.sync_every = int(sync_every)
         self._events: list[dict] = []
         self._lock = threading.Lock()
         self._epoch_ns = time.perf_counter_ns()
@@ -103,14 +142,10 @@ class Tracer:
         """Whether spans record events."""
         return self._enabled
 
-    def configure(self, *, enabled: bool | None = None,
-                  sync_every: int | None = None) -> None:
-        """Toggle recording and/or set the sampled-sync period (0 = never
-        sync; N = block_until_ready every N-th ``maybe_sync`` tick)."""
+    def configure(self, *, enabled: bool | None = None) -> None:
+        """Toggle recording."""
         if enabled is not None:
             self._enabled = bool(enabled)
-        if sync_every is not None:
-            self.sync_every = int(sync_every)
 
     def span(self, name: str, **args):
         """Context manager timing one stage. No-op while disabled or while
@@ -119,20 +154,13 @@ class Tracer:
             return _NULL
         return _Span(self, name, args)
 
-    def maybe_sync(self, name: str, value, tick: int) -> bool:
-        """Sampled device-time attribution: every ``sync_every``-th tick,
-        ``block_until_ready(value)`` under a span named ``name`` (with
-        ``sampled: True`` in its args). Returns True iff it synced."""
-        if (
-            not self._enabled
-            or self.sync_every <= 0
-            or tick % self.sync_every
-            or tracing_active()
-        ):
-            return False
-        with self.span(name, sampled=True, tick=tick):
-            jax.block_until_ready(value)
-        return True
+    def record(self, name: str, t0_ns: int, **args) -> None:
+        """Record one event from ``t0_ns`` (a ``time.perf_counter_ns``
+        reading) to now, for an interval that crosses calls and so cannot
+        be a span; its ``path`` is its name, and it makes no profiler
+        annotation. No-op while disabled."""
+        if self._enabled:
+            self._record(name, t0_ns, time.perf_counter_ns() - t0_ns, name, args)
 
     def _record(self, name, t0_ns, dur_ns, path, args) -> None:
         ev = {
@@ -151,7 +179,8 @@ class Tracer:
     # -- export -----------------------------------------------------------
 
     def events(self) -> list[dict]:
-        """The recorded Chrome trace events (copy)."""
+        """The recorded Chrome trace events (copy). ``ts`` is microseconds
+        of ``time.perf_counter`` since the tracer was built."""
         with self._lock:
             return list(self._events)
 
@@ -171,7 +200,7 @@ class Tracer:
         return path
 
     def stage_totals(self) -> dict:
-        """Total seconds per span name — the per-stage profile the ingest
+        """Total seconds per event name — the per-stage profile the ingest
         benchmark folds into its cumulative JSON."""
         out: dict[str, float] = {}
         for ev in self.events():
@@ -180,6 +209,23 @@ class Tracer:
 
 
 _DEFAULT = Tracer()
+_LISTENING = False
+
+
+def _on_compile(event: str, duration_secs: float, **kwargs) -> None:
+    """``jax.monitoring`` duration listener: one ``jax/compile`` event and
+    one ``jax_compiles`` increment per backend compile, attributed to the
+    span the compile happened in. Returns at once while disabled."""
+    if not _DEFAULT._enabled or event != BACKEND_COMPILE_EVENT:
+        return
+    t1 = time.perf_counter_ns()
+    path = "/".join(_STACK.get())
+    fun = str(kwargs.get("fun_name", ""))
+    _DEFAULT._record(
+        COMPILE_EVENT, t1 - int(duration_secs * 1e9), int(duration_secs * 1e9),
+        path, {"fun": fun},
+    )
+    _M_COMPILES.labels(span=path, fun=fun).inc()
 
 
 def default_tracer() -> Tracer:
@@ -187,9 +233,14 @@ def default_tracer() -> Tracer:
     return _DEFAULT
 
 
-def configure(*, enabled: bool | None = None, sync_every: int | None = None) -> None:
-    """Configure the default tracer (see ``Tracer.configure``)."""
-    _DEFAULT.configure(enabled=enabled, sync_every=sync_every)
+def configure(*, enabled: bool | None = None) -> None:
+    """Configure the default tracer (see ``Tracer.configure``). The first
+    enable registers the compile listener (see module docstring)."""
+    global _LISTENING
+    _DEFAULT.configure(enabled=enabled)
+    if _DEFAULT.enabled and not _LISTENING:
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _LISTENING = True
 
 
 def enabled() -> bool:
@@ -202,9 +253,9 @@ def span(name: str, **args):
     return _DEFAULT.span(name, **args)
 
 
-def maybe_sync(name: str, value, tick: int) -> bool:
-    """Sampled sync on the default tracer (see ``Tracer.maybe_sync``)."""
-    return _DEFAULT.maybe_sync(name, value, tick)
+def record(name: str, t0_ns: int, **args) -> None:
+    """An interval event on the default tracer (see ``Tracer.record``)."""
+    _DEFAULT.record(name, t0_ns, **args)
 
 
 def events() -> list[dict]:

@@ -263,6 +263,9 @@ class IngestPipeline:
         ]
         self._cur = 0  # which staging buffer is filling
         self._fill = 0  # elements in the filling buffer
+        # perf_counter_ns when the filling buffer took its first element,
+        # kept only while tracing (the ``ingest/fill`` event at its seal).
+        self._fill_t0 = None
         self._inflight: list = []  # retire queue of per-batch tickets
         # Readiness probe, overridable by tests to force backpressure
         # schedules deterministically.
@@ -298,8 +301,11 @@ class IngestPipeline:
         self.stats.pushed += len(keys)
         b = self.icfg.batch_size
         off = 0
+        tracing = obs_trace.enabled()
         with obs_trace.span("ingest/push", n=len(keys)):
             while off < len(keys):
+                if tracing and not self._fill:
+                    self._fill_t0 = time.perf_counter_ns()
                 take = min(b - self._fill, len(keys) - off)
                 buf = self._staging[self._cur]
                 sl = slice(self._fill, self._fill + take)
@@ -400,6 +406,10 @@ class IngestPipeline:
 
     def _dispatch(self, partial: bool = False) -> None:
         n, buf = self._fill, self._staging[self._cur]
+        if self._fill_t0 is not None:
+            # Staging wait: first element in to seal, across pushes.
+            obs_trace.record("ingest/fill", self._fill_t0, n=n, partial=partial)
+            self._fill_t0 = None
         # Swap staging buffers BEFORE transfer: the next push fills the other
         # buffer while this one's bytes are (asynchronously) consumed.
         self._cur ^= 1
@@ -427,10 +437,6 @@ class IngestPipeline:
         self.stats.batches += 1
         self.stats.partial_batches += bool(partial)
         self.stats.max_in_flight = max(self.stats.max_in_flight, len(self._inflight))
-        # Sampled device-time attribution: every sync_every-th batch blocks
-        # on its own ticket under a span (obs/trace.py — the sampled batch
-        # trades away its overlap for an honest device-side duration).
-        obs_trace.maybe_sync("ingest/device_sync", ticket, self.stats.batches)
 
 
 def _ticketed(update):
@@ -593,11 +599,16 @@ class TenantWindowIngest:
         through the key directory, then stage the slot-keyed elements.
         Masked elements are filtered host-side before staging (identical
         results to in-batch masking by the mask no-op contract)."""
-        slots, self.directory = key_directory.route(
-            self.dcfg, self.directory, tenant_keys, mask=mask,
-            epoch=jnp.int32(self._epoch),
-        )
-        slots = np.asarray(slots).ravel()
+        with obs_trace.span("ingest/route"):
+            slots, self.directory = key_directory.route(
+                self.dcfg, self.directory, tenant_keys, mask=mask,
+                epoch=jnp.int32(self._epoch),
+            )
+        # The host read of the slots waits for everything queued on the
+        # device ahead of the route (earlier updates, a rotation).
+        with obs_trace.span("ingest/route_wait"):
+            slots = np.asarray(slots)
+        slots = slots.ravel()
         ids = np.asarray(ids).ravel()
         w = None if weights is None else np.asarray(weights).ravel()
         if mask is not None:

@@ -376,3 +376,46 @@ def test_tenant_window_ingest_matches_synchronous_routing():
         jnp.sum((ref_dir.fingerprints != 0).astype(jnp.int32))
     )
     assert 0.0 <= met["tenant_collision_rate"] <= 1.0
+
+
+def test_tenant_window_ingest_spans_leave_state_unchanged():
+    """Traced, the front records one ``ingest/route`` and one
+    ``ingest/route_wait`` per push and one ``ingest/fill`` per sealed
+    micro-batch (flush / rotate seals flagged partial); its state is
+    bit-identical to the untraced run."""
+    from repro.obs import trace as obs_trace
+
+    dcfg = DirectoryConfig(capacity=K, seed=CFG.seed)
+    bsz, push = 96, 40  # pushes straddle batch boundaries
+
+    def drive(traced):
+        obs_trace.configure(enabled=traced)
+        obs_trace.clear()
+        try:
+            tw = ingest.TenantWindowIngest(
+                CFG, dcfg, n_epochs=3, icfg=ingest.IngestConfig(batch_size=bsz))
+            rng = np.random.default_rng(83)
+            for ep in range(3):
+                for _ in range(7):
+                    tw.push(rng.integers(0, 2**32, push, dtype=np.uint32),
+                            rng.integers(0, 2**32, push, dtype=np.uint32),
+                            (rng.gamma(1.0, 2.0, push) + 1e-5).astype(np.float32))
+                tw.rotate()
+            return tw.result(), tw.directory, obs_trace.events()
+        finally:
+            obs_trace.configure(enabled=False)
+            obs_trace.clear()
+
+    traced, traced_dir, events = drive(True)
+    plain, plain_dir, none = drive(False)
+    assert none == []
+    _assert_window_equal(traced, plain)
+    np.testing.assert_array_equal(np.asarray(traced_dir.fingerprints),
+                                  np.asarray(plain_dir.fingerprints))
+    names = [e["name"] for e in events]
+    assert names.count("ingest/route") == names.count("ingest/route_wait") == 21
+    assert names.count("ingest/push") == 21
+    fills = [e["args"] for e in events if e["name"] == "ingest/fill"]
+    # Per epoch 280 elements: two full batches of 96, then 88 sealed by rotate.
+    assert [(f["n"], f["partial"]) for f in fills] == [(96, False), (96, False), (88, True)] * 3
+    assert all(e["dur"] >= 0 for e in events)

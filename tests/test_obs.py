@@ -6,6 +6,8 @@ Coverage per the PR 9 acceptance list:
   reset, declaration idempotence/mismatch),
 * the disabled-mode no-op path (emissions ignored, snapshots empty),
 * trace span nesting + the Chrome trace-event JSON contract Perfetto loads,
+  spans as profiler annotations on the host plane of a ``.xplane.pb``, and
+  compiles recorded with the span that caused them,
 * ``health_report`` values against hand-built container states, including
   a deliberately top-bin-clamped int8 register plane,
 * a Prometheus text-format golden,
@@ -171,11 +173,101 @@ def test_trace_disabled_and_under_jit_noop():
     f(jnp.zeros(())).block_until_ready()
     assert seen[0] is obs_trace._NULL
     assert tr.events() == []
-    # maybe_sync only fires on the configured cadence.
-    tr.configure(sync_every=2)
-    assert not tr.maybe_sync("s", jnp.zeros(()), tick=1)
-    assert tr.maybe_sync("s", jnp.zeros(()), tick=2)
-    assert tr.events()[0]["args"]["sampled"] is True
+
+
+@pytest.fixture
+def default_trace():
+    """The default tracer enabled and empty; disabled and emptied after."""
+    obs_trace.configure(enabled=True)
+    obs_trace.clear()
+    yield obs_trace
+    obs_trace.configure(enabled=False)
+    obs_trace.clear()
+
+
+def _compiles(fun):
+    return [e for e in obs_trace.events()
+            if e["name"] == obs_trace.COMPILE_EVENT and e["args"]["fun"] == fun]
+
+
+def test_span_is_a_profiler_annotation_on_the_host_plane(tmp_path, default_trace):
+    import glob
+    import time
+
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_trace.span("qobs_probe_span"):
+            time.sleep(0.02)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = [
+        ev.duration_ns
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+        if ev.name == "qobs_probe_span"
+    ]
+    assert len(found) == 1
+    (ev,) = [e for e in obs_trace.events() if e["name"] == "qobs_probe_span"]
+    assert abs(found[0] / 1e3 - ev["dur"]) < 1e3  # µs: within 1 ms
+
+
+def test_disabled_tracer_records_no_span_or_compile(default_trace):
+    obs_trace.configure(enabled=False)
+    assert obs_trace.span("x") is obs_trace._NULL
+
+    def qobs_probe_off(x):
+        return x * 5 - 1
+
+    before = obs_trace._M_COMPILES.labels(span="", fun="jit(qobs_probe_off)").value
+    jax.jit(qobs_probe_off)(np.ones(3, np.float32)).block_until_ready()
+    assert obs_trace.events() == []
+    assert obs_trace._M_COMPILES.labels(span="", fun="jit(qobs_probe_off)").value == before
+
+
+def test_compile_is_recorded_with_its_enclosing_span(default_trace):
+    def qobs_probe_fn(x):
+        return x * 3 + 1
+
+    fam = obs_trace._M_COMPILES.labels(span="outer", fun="jit(qobs_probe_fn)")
+    before = fam.value
+    f = jax.jit(qobs_probe_fn)
+    with obs_trace.span("outer"):
+        f(np.ones(4, np.float32)).block_until_ready()
+    (ev,) = _compiles("jit(qobs_probe_fn)")
+    assert ev["args"]["path"] == "outer" and ev["dur"] > 0
+    (outer,) = [e for e in obs_trace.events() if e["name"] == "outer"]
+    assert outer["ts"] <= ev["ts"] and ev["ts"] + ev["dur"] <= outer["ts"] + outer["dur"] + 1
+    if obs_metrics.enabled():
+        assert fam.value == before + 1
+    # A second call hits the in-memory executable: no compile.
+    with obs_trace.span("outer"):
+        f(np.ones(4, np.float32)).block_until_ready()
+    assert len(_compiles("jit(qobs_probe_fn)")) == 1
+
+
+def test_compile_event_name_matches_jax():
+    from jax._src import dispatch
+
+    assert obs_trace.BACKEND_COMPILE_EVENT == dispatch.BACKEND_COMPILE_EVENT
+
+
+def test_record_interval_event():
+    import time
+
+    tr = Tracer(enabled=False)
+    t0 = time.perf_counter_ns()
+    tr.record("fill", t0, n=3)
+    assert tr.events() == []
+    tr.configure(enabled=True)
+    tr.record("fill", t0, n=3, partial=True)
+    (ev,) = tr.events()
+    assert ev["name"] == "fill" and ev["dur"] >= 0
+    assert ev["args"] == {"path": "fill", "n": 3, "partial": True}
 
 
 # ---------------------------------------------------------------------------
