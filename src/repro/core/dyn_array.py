@@ -19,12 +19,13 @@ key's batch-start histogram (Eq. 12 semantics per row). The K-loop oracle
 accumulate the same per-key terms in a different — but fixed — float32
 association order, equal to the loop within rounding).
 
-Update cost is O(B log B) (dedup sort) + O(B·2^b) (q_R) + O(B) scatters —
-independent of K. The histogram is maintained *incrementally*: each register
-changed by the batch moves one unit of mass old-bin -> new-bin, counted once
-via a per-(key, register) dedup — exactly equivalent to the single sketch's
-rebuild-from-registers because untouched registers hold r_min and bin 0 is
-pinned to zero (asserted against ``rebuild_hists`` in tests).
+Update cost is O(B log B) (dedup sort) + O(B·2^b) (q_R, histogram delta
+rows) + O(B) scatters — independent of K. The histogram is maintained
+*incrementally*: each register changed by the batch moves one unit of mass
+old-bin -> new-bin, counted once via a per-(key, register) dedup — exactly
+equivalent to the single sketch's rebuild-from-registers because untouched
+registers hold r_min and bin 0 is pinned to zero (asserted against
+``rebuild_hists`` in tests).
 
 Keyed martingale semantics (DESIGN.md §8.4): per-key chats ARE additive
 across disjoint batches of one stream (the martingale telescopes), but NOT
@@ -95,12 +96,16 @@ class UpdatePlan(typing.NamedTuple):
     """B-sized scatter payloads from the read-only half of one batch update.
 
     Produced by ``_plan_scatters`` (gathers + per-element math), consumed by
-    ``_commit_scatters`` (pure scatters). The split exists for the donated
-    hot path: when the gathers and the scatters of the same state buffer
-    share one executable, XLA's copy-insertion refuses to alias the donated
-    input and materialises full copies of the int32[K, 2^b] histograms
-    (~1 GiB per batch at K = 2^20) — compiling the halves as SEPARATE
-    executables keeps the commit scatter-only, which XLA updates in place.
+    ``_commit_scatters`` (pure scatters). The donated DynArray path compiles
+    the halves as SEPARATE executables, so the commit that receives the
+    donated state only scatters into it. What keeps that commit in place on
+    TPU is the scatters' shape: registers take a scalar scatter-max (an
+    ``int8[K, m]`` plane bitcasts to 1-D for free), and the histogram mass
+    moves land as ONE scatter-add of ``int32[B, 2^b]`` delta rows. A scalar
+    scatter at (key, bin) into the ``int32[K, 2^b]`` plane would make the
+    TPU compiler flatten the plane to 1-D first: the plane is tiled
+    T(8,128), not row-major, so the flattening is a relayout copy out and a
+    reshape back, two passes over the whole plane per batch.
     """
 
     keys: jax.Array  # int32[B] clipped row routes
@@ -114,16 +119,21 @@ class UpdatePlan(typing.NamedTuple):
 
 
 def _plan_scatters(
-    cfg: SketchConfig, state: DynArrayState, keys, lo, hi, w, live, q
+    cfg: SketchConfig, state: DynArrayState, keys, lo, hi, w, live, q, *ring
 ) -> UpdatePlan:
     """Read-only half of the update: dedup, batch-start change indicators,
     incremental-histogram bookkeeping — every output is B-sized and state
     is only gathered, never written. ``q`` is the per-element update
-    probability from the element's key's batch-start histogram."""
+    probability from the element's key's batch-start histogram.
+
+    ``ring``: empty for a DynArray; the window's ``head`` when the leaves
+    of ``state`` are whole ``[E, K, ...]`` ring planes (core/window_array.py)
+    — the registers are then gathered at (head, key, j), with no epoch
+    slice of the ring."""
     j, y = qsketch_dyn._choose_and_quantize(cfg, lo, hi, w)
 
     alive = _keyed_dedup_mask(keys, lo, hi, live) & live
-    old = state.regs[keys, j].astype(jnp.int32)
+    old = state.regs[(*ring, keys, j)].astype(jnp.int32)
     changed = alive & (y > old)
 
     chat_add = jnp.where(changed, w / q, 0.0)
@@ -168,24 +178,47 @@ def _plan_scatters(
     )
 
 
-def _commit_scatters(state: DynArrayState, plan: UpdatePlan) -> DynArrayState:
+def _hist_delta_rows(plan: UpdatePlan, num_bins: int) -> jax.Array:
+    """``int32[B, 2^b]`` histogram mass moves, one row per element: -1 at
+    ``old_bin`` where it retires mass, +1 at ``final_bin`` where it deposits
+    it. A one-hot built elementwise — B-sized, no scatter."""
+    bins = jnp.arange(num_bins, dtype=jnp.int32)
+    return (
+        jnp.where(bins == plan.old_bin[:, None], plan.hist_dec[:, None], 0)
+        + jnp.where(bins == plan.final_bin[:, None], plan.hist_inc[:, None], 0)
+    )
+
+
+def _commit_scatters(state: DynArrayState, plan: UpdatePlan, *ring) -> DynArrayState:
     """Scatter-only half of the update: register scatter-max, histogram
-    mass moves, martingale accumulation. Every state leaf is written, never
-    gathered — the shape XLA aliases in place under donation."""
-    regs = state.regs.at[plan.keys, plan.j].max(plan.y_eff)
-    hists = state.hists.at[plan.keys, plan.old_bin].add(plan.hist_dec)
-    hists = hists.at[plan.keys, plan.final_bin].add(plan.hist_inc)
-    chats = state.chats.at[plan.keys].add(plan.chat_add)
+    mass moves, martingale accumulation. Every state leaf is written only
+    at the rows the batch addresses, never gathered. The histogram moves
+    are one scatter-add of whole delta rows at ``keys`` (``UpdatePlan``
+    says why not two scalar scatters); integer adds commute, so duplicate
+    keys in a batch give the same bits in any order.
+
+    ``ring``: as in ``_plan_scatters`` — the window's ``head`` when the
+    leaves are whole ring planes, so the rows land straight in that epoch
+    with no slice taken or written back."""
+    rows = (*ring, plan.keys)
+    regs = state.regs.at[(*rows, plan.j)].max(plan.y_eff)
+    hists = state.hists.at[rows].add(
+        _hist_delta_rows(plan, state.hists.shape[-1])
+    )
+    chats = state.chats.at[rows].add(plan.chat_add)
     return DynArrayState(regs=regs, hists=hists, chats=chats)
 
 
-def _apply_update(cfg: SketchConfig, state: DynArrayState, keys, lo, hi, w, live, q):
+def _apply_update(
+    cfg: SketchConfig, state: DynArrayState, keys, lo, hi, w, live, q, *ring
+):
     """Shared tail of the jnp and Pallas-backed update paths: the plan and
     commit halves fused back into one trace. The sharded/window/kernel
     routes and the non-donated ``update_batch`` all come through here, so
-    every route runs the identical math as the split donated path."""
+    every route runs the identical math as the split donated path.
+    ``ring`` as in ``_plan_scatters``."""
     return _commit_scatters(
-        state, _plan_scatters(cfg, state, keys, lo, hi, w, live, q)
+        state, _plan_scatters(cfg, state, keys, lo, hi, w, live, q, *ring), *ring
     )
 
 
@@ -231,13 +264,14 @@ def update_batch(
       (``donate_argnums``) — so the scatters reuse the state buffers
       instead of allocating a fresh int8[K, m] + int32[K, 2^b] + f32[K]
       copy per batch: the steady-state ingest mode (sketchstream/ingest.py).
-      The split matters because a single executable that both gathers and
-      scatters a donated buffer makes XLA's copy-insertion bail out of
-      aliasing and COPY the histograms anyway (measured ~10x slower at
-      K = 2^20). The caller's ``state`` is DEAD afterwards (same values
-      live on in the returned state); keep ``donate=False`` anywhere the
-      old state is still read (oracles, merges, A/B tests). Both modes are
-      bit-identical: the plan/commit math is one trace, split or fused.
+      The compiled commit writes only the B addressed rows of each leaf:
+      on a TPU v5e its temporaries are B-sized, with no copy or reshape of
+      a plane (tests/test_tpu_compile.py), because the histogram moves are
+      one row scatter-add (``UpdatePlan``). The caller's ``state`` is DEAD
+      afterwards (same values live on in the returned state); keep
+      ``donate=False`` anywhere the old state is still read (oracles,
+      merges, A/B tests). Both modes are bit-identical: the plan/commit
+      math is one trace, split or fused.
     """
     if donate:
         return _commit_donated(state, _plan_batch_jit(cfg, state, keys, ids, weights, mask))

@@ -98,22 +98,27 @@ def union_substate(state: WindowArrayState) -> DynArrayState:
 
 def _apply_update(cfg: SketchConfig, state: WindowArrayState, keys, lo, hi, w, live):
     """Shared tail of the single-host and sharded windowed updates: two fused
-    DynArray updates on the same dedup'd elements — the head epoch sub-state
-    and the union cache. ``keys`` are in-range row indices and ``live`` is
-    the final element mask (padding, degenerate weights and — in the sharded
-    form — foreign shards' elements already dropped)."""
-    ep = epoch_substate(state, state.head)
-    q_ep = qsketch_dyn._q_update_prob(cfg, ep.hists[keys], w)
-    ep = dyn_array._apply_update(cfg, ep, keys, lo, hi, w, live, q_ep)
+    DynArray updates on the same dedup'd elements — the head epoch and the
+    union cache. ``keys`` are in-range row indices and ``live`` is the final
+    element mask (padding, degenerate weights and — in the sharded form —
+    foreign shards' elements already dropped).
+
+    The head epoch is updated in the ring itself: its batch-start rows are
+    gathered at (head, keys) and the scatters land at (head, keys, ...), so
+    no epoch plane is sliced out of the ring or written back into it."""
+    head = state.head
+    ring = DynArrayState(regs=state.regs, hists=state.hists, chats=state.chats)
+    q_ep = qsketch_dyn._q_update_prob(cfg, state.hists[head, keys], w)
+    ep = dyn_array._apply_update(cfg, ring, keys, lo, hi, w, live, q_ep, head)
 
     un = union_substate(state)
     q_un = qsketch_dyn._q_update_prob(cfg, un.hists[keys], w)
     un = dyn_array._apply_update(cfg, un, keys, lo, hi, w, live, q_un)
 
     return state._replace(
-        regs=state.regs.at[state.head].set(ep.regs),
-        hists=state.hists.at[state.head].set(ep.hists),
-        chats=state.chats.at[state.head].set(ep.chats),
+        regs=ep.regs,
+        hists=ep.hists,
+        chats=ep.chats,
         union_regs=un.regs,
         union_hists=un.hists,
         union_chats=un.chats,
@@ -159,7 +164,12 @@ def update_batch(
     ``donate=True`` hands the (large: int8[E, K, m] + int32[E, K, 2^b]) ring
     state to XLA for in-place reuse — the steady-state ingest mode; the
     caller's ``state`` is dead afterwards (``dyn_array.update_batch`` has the
-    full contract).
+    full contract). Both halves scatter only the B addressed rows: the head
+    epoch is updated at (head, keys) in the ring, never sliced out and
+    written back, and the histogram moves are row scatter-adds
+    (``dyn_array.UpdatePlan``). Compiled for a TPU v5e, the donated update's
+    temporaries are B-sized, with no copy, reshape or (dynamic) slice of a
+    plane (tests/test_tpu_compile.py).
     """
     fn = _update_batch_donated if donate else _update_batch_jit
     return fn(cfg, state, keys, ids, weights, mask)

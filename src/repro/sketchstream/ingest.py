@@ -464,15 +464,15 @@ def _dyn_update_fn(cfg: SketchConfig, use_kernel: bool, interpret: bool | None =
 
         return jax.jit(_ticketed(upd), donate_argnums=(0,))
 
-    # The jnp route stays OUTSIDE any enclosing jit on purpose: donate=True
-    # runs the update as two executables (read-only plan + scatter-only
-    # donating commit, core/dyn_array.py) — wrapping them in one jit would
-    # fuse them back into the gather+scatter shape whose copy-insertion
-    # re-copies the [K, 2^b] histograms every batch. The ticket is a third,
-    # O(1) dispatch chained on the committed state.
+    # The jnp route stays OUTSIDE any enclosing jit: donate=True runs the
+    # update as two executables (read-only plan + scatter-only donating
+    # commit, core/dyn_array.py), each timed on its own in a device trace.
+    # The ticket is a third, O(1) dispatch chained on the committed state:
+    # one element of the commit's output (an eager ``ravel`` would copy the
+    # whole register plane into a new array every batch).
     def fn(st, keys, ids, w, mask):
         out = dyn_array.update_batch(cfg, st, keys, ids, w, mask, donate=True)
-        return out, out.regs.ravel()[0]
+        return out, out.chats[0]
 
     return fn
 

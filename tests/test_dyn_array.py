@@ -41,19 +41,48 @@ def _assert_states_match(st, ref, chat_rtol=1e-5):
     )
 
 
-@pytest.mark.parametrize("batch,m,k", SHAPES)
-def test_update_matches_k_loop_oracle(batch, m, k):
-    """Row r == a standalone qsketch_dyn.update_batch fed the key-r sub-stream."""
+# Cases of the commit's histogram row scatter-add: (batch, m, K, masked
+# share). "hot_rows" puts 2048 events on each of 2 tenants (128 per
+# register), so many delta rows of one batch land on the same key;
+# "masked_padding" masks 30% of the rows; "hot_masked" does both. Each runs
+# on the fused and the split donated (plan + commit) path.
+ROW_SCATTER_CASES = {
+    "hot_rows": (4096, 16, 2, 0.0),
+    "masked_padding": (1024, 64, 12, 0.3),
+    "hot_masked": (4096, 16, 3, 0.5),
+}
+ORACLE_CASES = [
+    pytest.param(*shape, 0.0, False, id="-".join(map(str, shape)))
+    for shape in SHAPES
+] + [
+    pytest.param(*case, donate, id=f"{name}-{'donated' if donate else 'fused'}")
+    for name, case in sorted(ROW_SCATTER_CASES.items())
+    for donate in (False, True)
+]
+
+
+@pytest.mark.parametrize("batch,m,k,masked,donate", ORACLE_CASES)
+def test_update_matches_k_loop_oracle(batch, m, k, masked, donate):
+    """Row r == a standalone qsketch_dyn.update_batch fed the key-r
+    sub-stream, over a cold and a warm batch (q_R then reads nonzero
+    histograms). Masked rows are routed, as the ingest pipeline pads, to
+    key 0 with a live row's id."""
     cfg = SketchConfig(m=m, b=8, seed=batch + m + k)
-    keys, ids, w = _keyed_stream(batch, k, seed=batch * 7 + k)
-    st = dyn_array.update_batch(cfg, dyn_array.init(cfg, k), keys, ids, w)
-    ref = dyn_array.update_reference(cfg, dyn_array.init(cfg, k), keys, ids, w)
-    _assert_states_match(st, ref)
-    # Second batch on the warm state: q_R now reads nonzero histograms.
-    keys2, ids2, w2 = _keyed_stream(batch, k, seed=batch * 7 + k + 1)
-    _assert_states_match(
-        dyn_array.update_batch(cfg, st, keys2, ids2, w2),
-        dyn_array.update_reference(cfg, ref, keys2, ids2, w2),
+    rng = np.random.default_rng(batch * 5 + k)
+    st = dyn_array.init(cfg, k)
+    ref = dyn_array.init(cfg, k)
+    for i in range(2):
+        keys, ids, w = _keyed_stream(batch, k, seed=batch * 7 + k + i)
+        mask = rng.random(batch) >= masked
+        keys = jnp.where(mask, keys, 0)
+        ids = jnp.where(mask, ids, ids[0])
+        ref = dyn_array.update_reference(cfg, ref, keys, ids, w, mask=mask)
+        st = dyn_array.update_batch(
+            cfg, st, keys, ids, w, mask=jnp.asarray(mask), donate=donate
+        )
+        _assert_states_match(st, ref)
+    np.testing.assert_array_equal(
+        np.asarray(st.hists), np.asarray(dyn_array.rebuild_hists(cfg, st.regs))
     )
 
 

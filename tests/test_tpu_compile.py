@@ -1,11 +1,13 @@
-"""Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e.
+"""Ahead-of-time compiles of the main-path executables for a TPU v5e.
 
-Each test lowers one kernel entry at the widths of the chip deployment
-(K = 2^20 tenant rows, m = 128 registers, 2^8 histogram bins, E = 4 epochs,
-micro-batches of 16384 and 65536 elements) with ``interpret=False`` and
-compiles it for a described (not attached) v5e chip: what Mosaic or the TPU
-compiler refuses fails here, without a chip. Nothing runs, so nothing about
-results or times is checked.
+Each kernel test lowers one Pallas entry at the widths of the chip
+deployment (K = 2^20 tenant rows, m = 128 registers, 2^8 histogram bins,
+E = 4 epochs, micro-batches of 16384 and 65536 elements) with
+``interpret=False`` and compiles it for a described (not attached) v5e
+chip: what Mosaic or the TPU compiler refuses fails here, without a chip.
+The container-update tests compile the donated per-batch updates at the
+same widths and check from the compiled module that they stay in place.
+Nothing runs, so nothing about results or times is checked.
 
 The topology is described inside a module fixture, never at import: only one
 process at a time may load the TPU compiler library, so describing it while
@@ -14,12 +16,18 @@ persistent compilation cache is off around the compiles (a compile for a
 described chip cannot be read back without one).
 """
 
+import functools
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import SketchConfig, dyn_array, window_array
 from repro.kernels import dyn_array_update, estimate, window_union
+from repro.sketchstream import ingest
 
 K, M, NB, E = 2**20, 128, 256, 4
 R_MIN, TOP_BIN = -127, 254
@@ -83,3 +91,51 @@ def test_estimate_rows_compiles_for_v5e(one_chip):
         ),
         jax.ShapeDtypeStruct((K, M), jnp.int8, sharding=one_chip),
     )
+
+
+# Ops that move a whole state plane when their result is plane-sized: the
+# TPU scatter's 1-D relayout (copy out, reshape back) and the epoch slice
+# and write-back of a ring plane.
+_PLANE_OPS = ("copy", "reshape", "dynamic-slice", "dynamic-update-slice")
+# "%name = s32[1048576,256]{1,0:T(8,128)} reshape(...)": dims and opcode of
+# every array-valued instruction, fused computations included.
+_HLO_OP = re.compile(r"= [a-z][a-z0-9]*\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def _plane_passes(hlo: str, min_elems: int) -> list:
+    """(opcode, dims) of every ``_PLANE_OPS`` instruction of an optimized
+    HLO module whose result holds at least ``min_elems`` elements."""
+    return [
+        (op, dims)
+        for dims, op in _HLO_OP.findall(hlo)
+        if op in _PLANE_OPS
+        and math.prod(int(d) for d in dims.split(",") if d) >= min_elems
+    ]
+
+
+def _update_executable(which, one_chip):
+    cfg = SketchConfig(m=M, b=8, seed=0)
+    b = 16384
+    sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    shapes = lambda tree: jax.tree.map(lambda s: sd(s.shape, s.dtype), tree)
+    batch = (sd((b,), jnp.int32), sd((b,), jnp.uint32), sd((b,), jnp.float32),
+             sd((b,), jnp.bool_))
+    if which == "dyn_commit":
+        st = shapes(jax.eval_shape(functools.partial(dyn_array.init, cfg, K)))
+        plan = shapes(jax.eval_shape(
+            lambda s, *a: dyn_array._plan_batch(cfg, s, *a), st, *batch))
+        return dyn_array._commit_donated.lower(st, plan).compile()
+    st = shapes(jax.eval_shape(functools.partial(window_array.init, cfg, K, E)))
+    return ingest._window_update_fn(cfg).lower(st, *batch).compile()
+
+
+@pytest.mark.parametrize("which", ["dyn_commit", "window_update"])
+def test_container_update_stays_in_place_on_v5e(one_chip, which):
+    """The donated per-batch update writes only the rows the batch
+    addresses: B-sized temporaries (the plane, 1 GiB here, is larger than
+    on-chip memory, so a plane-sized temporary cannot hide there) and no
+    plane-sized copy, reshape or epoch slice in the optimized module."""
+    compiled = _update_executable(which, one_chip)
+    plane_bytes = K * NB * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < plane_bytes // 16
+    assert _plane_passes(compiled.as_text(), K * M) == []

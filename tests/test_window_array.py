@@ -59,30 +59,58 @@ def _oracle_window_estimate(cfg, k, logs, w):
     return np.asarray(dyn_array.estimate_mle_rows(cfg, union))
 
 
-@pytest.mark.parametrize("batch,m,k,e", SHAPES)
-def test_update_matches_k_loop_oracle(batch, m, k, e):
-    """Fused windowed update == K-loop reference on head epoch AND union."""
+# Cases of the update written straight into the ring at (head, keys):
+# (batch, m, K, E, rotations, masked share). After 6 rotations of E = 4 the
+# head is 2 and every ring slot has been reused; few tenants and registers
+# put many delta rows on one key in one scatter-add. Each runs on the fused
+# and the donated path.
+WRAPPED_HEAD_CASES = {
+    "wrapped_head": (2048, 16, 3, 4, 6, 0.0),
+    "wrapped_head_masked": (2048, 16, 3, 4, 6, 0.4),
+}
+ORACLE_CASES = [
+    pytest.param(*shape, 0, 0.0, False, id="-".join(map(str, shape)))
+    for shape in SHAPES
+] + [
+    pytest.param(*case, donate, id=f"{name}-{'donated' if donate else 'fused'}")
+    for name, case in sorted(WRAPPED_HEAD_CASES.items())
+    for donate in (False, True)
+]
+
+
+@pytest.mark.parametrize("batch,m,k,e,rotations,masked,donate", ORACLE_CASES)
+def test_update_matches_k_loop_oracle(batch, m, k, e, rotations, masked, donate):
+    """Windowed update == K-loop reference on every epoch plane AND the
+    union, over two batches (the second reads warm histograms), after
+    ``rotations`` rotations. Masked rows are routed, as the ingest pipeline
+    pads, to key 0 with a live row's id."""
     cfg = SketchConfig(m=m, b=8, seed=batch + m + k)
-    st = window_array.init(cfg, k, e)
-    ref = window_array.init(cfg, k, e)
-    for i in range(2):  # second batch reads warm histograms
+    st = (
+        _drive(cfg, k, e, n_epochs=rotations + 1, batch=256, seed=5)[0]
+        if rotations
+        else window_array.init(cfg, k, e)
+    )
+    assert int(st.head) == rotations % e
+    ref = jax.tree.map(lambda x: jnp.array(x, copy=True), st)
+    rng = np.random.default_rng(batch * 5 + k)
+    for i in range(2):
         keys, ids, w = _keyed_stream(batch, k, seed=batch * 7 + k + i)
-        st = window_array.update_batch(cfg, st, keys, ids, w)
-        ref = window_array.update_reference(cfg, ref, keys, ids, w)
-    np.testing.assert_array_equal(np.asarray(st.regs), np.asarray(ref.regs))
-    np.testing.assert_array_equal(np.asarray(st.hists), np.asarray(ref.hists))
-    np.testing.assert_array_equal(
-        np.asarray(st.union_regs), np.asarray(ref.union_regs)
-    )
-    np.testing.assert_array_equal(
-        np.asarray(st.union_hists), np.asarray(ref.union_hists)
-    )
-    np.testing.assert_allclose(
-        np.asarray(st.chats), np.asarray(ref.chats), rtol=1e-5, atol=1e-6
-    )
-    np.testing.assert_allclose(
-        np.asarray(st.union_chats), np.asarray(ref.union_chats), rtol=1e-5, atol=1e-6
-    )
+        mask = rng.random(batch) >= masked
+        keys = jnp.where(mask, keys, 0)
+        ids = jnp.where(mask, ids, ids[0])
+        ref = window_array.update_reference(cfg, ref, keys, ids, w, mask=mask)
+        st = window_array.update_batch(
+            cfg, st, keys, ids, w, mask=jnp.asarray(mask), donate=donate
+        )
+        for f in ("regs", "hists", "union_regs", "union_hists", "head"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(st, f)), np.asarray(getattr(ref, f)), err_msg=f
+            )
+        for f in ("chats", "union_chats"):
+            np.testing.assert_allclose(
+                np.asarray(getattr(st, f)), np.asarray(getattr(ref, f)),
+                rtol=1e-5, atol=1e-6, err_msg=f,
+            )
 
 
 def test_union_cache_invariant_across_rotations():
