@@ -53,11 +53,12 @@ padded_k = sharding.padded_k
 
 
 def init(cfg: SketchConfig, k: int, mesh, axis: str = AXIS) -> ShardedArrayState:
-    """K fresh sketches, rows sharded over ``axis`` of ``mesh``."""
-    sharding.check_divisible(k, mesh, axis)
-    regs = jnp.full((k, cfg.m), cfg.r_min, dtype=jnp.int8)
+    """K fresh sketches, rows sharded over ``axis`` of ``mesh``, each
+    device building only its own rows (``sharding.init_rows``)."""
     return ShardedArrayState(
-        regs=sharding.device_put_rows(regs, mesh, 0, axis)
+        regs=sharding.init_rows(
+            lambda: jnp.full((k, cfg.m), cfg.r_min, dtype=jnp.int8), mesh, 0, axis
+        )
     )
 
 

@@ -50,10 +50,10 @@ DIMS = ShardedDynArrayState(regs=0, hists=0, chats=0)
 
 
 def init(cfg: SketchConfig, k: int, mesh, axis: str = AXIS) -> ShardedDynArrayState:
-    """K fresh Dyn sketches, all three leaves row-sharded over ``axis``."""
-    sharding.check_divisible(k, mesh, axis)
+    """K fresh Dyn sketches, all three leaves row-sharded over ``axis``,
+    each device building only its own rows (``sharding.init_rows``)."""
     return ShardedDynArrayState(
-        *sharding.device_put_rows(dyn_array.init(cfg, k), mesh, DIMS, axis)
+        *sharding.init_rows(lambda: dyn_array.init(cfg, k), mesh, DIMS, axis)
     )
 
 

@@ -60,10 +60,10 @@ _ARRAY_DIMS = (1, 1, 1, 0, 0, 0)  # the six per-tenant leaves, in state order
 
 
 def init(cfg: SketchConfig, k: int, e: int, mesh, axis: str = AXIS) -> ShardedWindowArrayState:
-    """K tenants x E ring epochs, per-tenant leaves sharded over ``axis``."""
-    sharding.check_divisible(k, mesh, axis)
+    """K tenants x E ring epochs, per-tenant leaves sharded over ``axis``,
+    each device building only its own rows (``sharding.init_rows``)."""
     return ShardedWindowArrayState(
-        *sharding.device_put_rows(window_array.init(cfg, k, e), mesh, DIMS, axis)
+        *sharding.init_rows(lambda: window_array.init(cfg, k, e), mesh, DIMS, axis)
     )
 
 

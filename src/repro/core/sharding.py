@@ -15,7 +15,9 @@ that machinery so every sharded front (``sharded_array``,
   ``WindowArrayState`` epoch planes are ``row_dim=1`` with replicated ring
   scalars.
 * **Placement** (``device_put_rows``) — reshard a host pytree onto the mesh
-  (pure data movement, values unchanged).
+  (pure data movement, values unchanged); ``init_rows`` builds a fresh
+  state straight into its row shardings, so no device ever holds more
+  than its own rows (a state larger than one chip can only be made so).
 * **shard_map wrapping** (``shard_map_rows``) — wrap a *shard-local*
   function so it runs per shard over row-sharded pytrees; replicated args
   (batches, ring scalars) are broadcast. The local function sees plain
@@ -25,7 +27,7 @@ that machinery so every sharded front (``sharded_array``,
   the replicated batch down to the slot range this shard owns and rebase
   slots to local row indices. Every element updates exactly the shard that
   owns its row; no collective is needed and register state never leaves its
-  shard.
+  shard. ``shard_counts`` is the same layout on the host (telemetry).
 * **All-max merge** — cross-pod merges stay element-wise ``jnp.maximum``
   on the sharded arrays themselves (the max monoid needs no resharding);
   ``check_same_shape`` is the shared validation.
@@ -39,6 +41,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 AXIS = "sketch"
@@ -108,6 +111,36 @@ def device_put_rows(tree, mesh, row_dims, axis: str = AXIS):
     return jax.tree.unflatten(treedef, out)
 
 
+def init_rows(init_fn, mesh, row_dims, axis: str = AXIS):
+    """A fresh state built in place on ``mesh``: ``init_fn()`` (the
+    container's own single-host ``init``, closed over its arguments) jitted
+    with each leaf's row sharding as its output sharding, so every device
+    fills only its own K/S rows. Values and shardings are those of
+    ``device_put_rows(init_fn(), mesh, row_dims, axis)``; ``row_dims`` is
+    matched leaf-wise, as there."""
+    fill, treedef = init_rows_jit(init_fn, mesh, row_dims, axis)
+    return jax.tree.unflatten(treedef, fill())
+
+
+def init_rows_jit(init_fn, mesh, row_dims, axis: str = AXIS):
+    """``init_rows``' executable, not yet run: (the jitted fill of the
+    state's leaves, in leaf order; the state's treedef)."""
+    leaves, treedef = jax.tree.flatten(jax.eval_shape(init_fn))
+    dims = jax.tree.leaves(row_dims, is_leaf=lambda d: d is None)
+    if len(leaves) != len(dims):
+        raise ValueError(
+            f"row_dims has {len(dims)} leaves for a tree of {len(leaves)}"
+        )
+    for leaf, d in zip(leaves, dims):
+        if d is not None:
+            check_divisible(leaf.shape[d], mesh, axis)
+    fill = jax.jit(
+        lambda: jax.tree.leaves(init_fn()),
+        out_shardings=[NamedSharding(mesh, spec(d, axis)) for d in dims],
+    )
+    return fill, treedef
+
+
 def shard_map_rows(
     fn,
     mesh,
@@ -155,6 +188,16 @@ def own_slots(slots, rows: int, axis: str = AXIS, mask=None):
     if mask is not None:
         own = own & mask
     return jnp.clip(slots - lo, 0, rows - 1), own
+
+
+def shard_counts(slots: np.ndarray, k: int, shards: int) -> np.ndarray:
+    """Host-side twin of ``own_slots``' layout, for telemetry: how many of
+    the host array ``slots`` (global slots of a K-row state) each of the
+    ``shards`` contiguous row ranges owns. A slot outside [0, K) belongs to
+    no shard, as on the device."""
+    slots = np.asarray(slots)
+    live = slots[(slots >= 0) & (slots < k)]
+    return np.bincount(live // (k // shards), minlength=shards)
 
 
 def check_same_shape(a, b, what: str) -> None:

@@ -57,6 +57,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import (
     dyn_array,
@@ -96,6 +97,10 @@ _M_BARRIERS = obs_metrics.counter(
     "ingest_barriers", "retire barriers", labels=("pipe",))
 _M_IN_FLIGHT = obs_metrics.gauge(
     "ingest_in_flight", "unretired in-flight batches", labels=("pipe",))
+_M_SHARD_EVENTS = obs_metrics.counter(
+    "ingest_shard_events_total",
+    "dispatched events by the shard that owns their row (sharded pipelines)",
+    labels=("pipe", "shard"))
 
 _STAT_FAMILIES = {
     "pushed": _M_PUSHED,
@@ -161,50 +166,78 @@ class IngestStats:
     ``snapshot(delta=True)`` / ``reset()`` give interval reads and explicit
     re-arming (the ``max_in_flight`` high-water and ``stall_s`` total are
     per-lifetime, not per-process).
+
+    A pipe over ``shards`` row shards (the sharded fronts) also keeps
+    ``shard_events``: dispatched events by the shard that owns their row,
+    the ``ingest_shard_events_total{pipe, shard}`` family, and a
+    ``shard_events`` list in snapshots. It is telemetry only, added while
+    the registry or the tracer records (``add_shard_events``).
     """
 
     FIELDS = tuple(_STAT_FAMILIES)
 
-    def __init__(self, pipe: str | None = None):
+    def __init__(self, pipe: str | None = None, shards: int = 0):
         self.pipe = str(next(_PIPE_SEQ)) if pipe is None else str(pipe)
-        reg = obs_metrics.default_registry()
-        if reg.enabled:
+        self.shards = int(shards)
+        if obs_metrics.default_registry().enabled:
             self._series = {
                 f: fam.labels(pipe=self.pipe) for f, fam in _STAT_FAMILIES.items()
             }
-            # A reused label (explicit pipe= names, or a restarted process
-            # registry) must not inherit the previous lifetime's counts.
-            for s in self._series.values():
-                s.reset()
-            self._local = None
+            self._shard_series = [
+                _M_SHARD_EVENTS.labels(pipe=self.pipe, shard=i) for i in range(self.shards)
+            ]
         else:
             self._series = None
-            self._local = dict.fromkeys(self.FIELDS, 0)
-            self._local["stall_s"] = 0.0
-            self._delta = dict(self._local)
+        # A reused label (explicit pipe= names, or a restarted process
+        # registry) must not inherit the previous lifetime's counts.
+        self.reset()
+
+    @property
+    def shard_events(self) -> list[int]:
+        """Events dispatched so far, by owning shard."""
+        if self._series is not None:
+            return [s.value for s in self._shard_series]
+        return self._local_shards.tolist()
+
+    def add_shard_events(self, counts: np.ndarray) -> None:
+        """Add one micro-batch's events by owning shard."""
+        if self._series is not None:
+            for s, c in zip(self._shard_series, counts):
+                s.inc(int(c))
+        else:
+            self._local_shards += counts
 
     def snapshot(self, delta: bool = False) -> dict:
         """``{field: value}``; ``delta=True`` reports change since the
         previous delta snapshot and advances the baseline."""
         if self._series is not None:
-            return {f: s.read(delta) for f, s in self._series.items()}
-        if delta:
+            out = {f: s.read(delta) for f, s in self._series.items()}
+            shards = [s.read(delta) for s in self._shard_series]
+        elif delta:
             out = {f: self._local[f] - self._delta[f] for f in self.FIELDS}
             # Gauge semantics match the registry backend: report current.
             out["max_in_flight"] = self._local["max_in_flight"]
             self._delta = dict(self._local)
-            return out
-        return dict(self._local)
+            shards = (self._local_shards - self._delta_shards).tolist()
+            self._delta_shards = self._local_shards.copy()
+        else:
+            out = dict(self._local)
+            shards = self._local_shards.tolist()
+        if self.shards:
+            out["shard_events"] = shards
+        return out
 
     def reset(self) -> None:
         """Zero every counter, the high-water mark, and delta baselines."""
         if self._series is not None:
-            for s in self._series.values():
+            for s in (*self._series.values(), *self._shard_series):
                 s.reset()
         else:
             self._local = dict.fromkeys(self.FIELDS, 0)
             self._local["stall_s"] = 0.0
             self._delta = dict(self._local)
+            self._local_shards = np.zeros(self.shards, np.int64)
+            self._delta_shards = self._local_shards.copy()
 
 
 def _stat_property(field: str) -> property:
@@ -242,15 +275,28 @@ class IngestPipeline:
     the settled state), ``metrics`` (telemetry counters). The internally
     threaded state is donated batch-to-batch: never retain references to
     ``.state`` across a push.
+
+    On a ``mesh`` (the sharded constructors, with the container's K rows
+    split over ``axis``) each sealed micro-batch is placed replicated on
+    every device of the mesh. While the registry or the tracer records,
+    its events are counted by the shard that owns their row:
+    ``stats.shard_events`` and, while tracing, one ``ingest/shard_rows``
+    event per micro-batch with the per-shard ``counts``.
     """
 
     def __init__(self, icfg: IngestConfig, state, update_fn, *, rotate_fn=None,
-                 name: str | None = None):
+                 name: str | None = None, mesh=None, k: int | None = None,
+                 axis: str = sharding.AXIS):
         self.icfg = icfg
         self._state = state
         self._update = update_fn
         self._rotate = rotate_fn
-        self.stats = IngestStats(pipe=name)
+        # None: the default device, uncommitted (single-device fronts).
+        self._placement = None if mesh is None else NamedSharding(mesh, P())
+        self._k = k
+        self.stats = IngestStats(
+            pipe=name, shards=0 if mesh is None else sharding.num_shards(mesh, axis)
+        )
         b = icfg.batch_size
         self._staging = [
             {
@@ -418,18 +464,20 @@ class IngestPipeline:
             self.stats.dropped += n
             buf["mask"][:] = False
             return
+        if self.stats.shards and (obs_metrics.enabled() or obs_trace.enabled()):
+            self._count_shards(buf["keys"][:n])
         # Hand jax freshly-OWNED copies: the CPU backend may defer (or
-        # zero-copy alias) the host bytes passed to asarray until the
+        # zero-copy alias) the host bytes passed to device_put until the
         # consuming executable runs, and this buffer is mutated again as
         # soon as push() wraps around to it — with queue_depth > 2 that is
         # before the in-flight batch is guaranteed to have read its inputs.
         # The memcpy IS the staging->transfer hop; jax holds the only
         # reference afterwards, so later staging writes can never race it.
+        # On a mesh the copy goes to every device here, replicated, rather
+        # than inside the update's dispatch.
         with obs_trace.span("ingest/seal", n=n, partial=partial):
-            keys = jnp.asarray(buf["keys"].copy())
-            ids = jnp.asarray(buf["ids"].copy())
-            w = jnp.asarray(buf["w"].copy())
-            mask = jnp.asarray(buf["mask"].copy())
+            host = [buf[f].copy() for f in ("keys", "ids", "w", "mask")]
+            keys, ids, w, mask = jax.device_put(host, self._placement)
             buf["mask"][:] = False  # pre-cleared for this buffer's next fill
         with obs_trace.span("ingest/dispatch", n=n):
             self._state, ticket = self._update(self._state, keys, ids, w, mask)
@@ -437,6 +485,14 @@ class IngestPipeline:
         self.stats.batches += 1
         self.stats.partial_batches += bool(partial)
         self.stats.max_in_flight = max(self.stats.max_in_flight, len(self._inflight))
+
+    def _count_shards(self, keys: np.ndarray) -> None:
+        """Events of one sealed micro-batch by the shard that owns their
+        row: a host bincount of slots already on the host."""
+        t0 = time.perf_counter_ns()
+        counts = sharding.shard_counts(keys, self._k, self.stats.shards)
+        self.stats.add_shard_events(counts)
+        obs_trace.record("ingest/shard_rows", t0, counts=counts.tolist())
 
 
 def _ticketed(update):
@@ -528,9 +584,13 @@ def sharded_dyn_pipeline(
     cfg: SketchConfig, mesh, state, icfg: IngestConfig = IngestConfig(),
     *, axis: str = sharding.AXIS, name: str | None = None,
 ) -> IngestPipeline:
-    """Ingest front of a ShardedDynArray: the replicated staging batch is
-    hash-routed shard-locally inside one donating jit per micro-batch."""
-    return IngestPipeline(icfg, state, _sharded_dyn_update_fn(cfg, mesh, axis), name=name)
+    """Ingest front of a ShardedDynArray: the staging batch, sealed
+    replicated onto the mesh, is hash-routed shard-locally inside one
+    donating jit per micro-batch."""
+    return IngestPipeline(
+        icfg, state, _sharded_dyn_update_fn(cfg, mesh, axis), name=name,
+        mesh=mesh, k=sharded_dyn_array.num_sketches(state), axis=axis,
+    )
 
 
 @functools.lru_cache(maxsize=32)
@@ -547,12 +607,13 @@ def sharded_window_pipeline(
     cfg: SketchConfig, mesh, state, icfg: IngestConfig = IngestConfig(),
     *, axis: str = sharding.AXIS, name: str | None = None,
 ) -> IngestPipeline:
-    """Ingest front of a ShardedWindowArray: hash-routed donated updates
-    plus the donated shard-local ring rotation behind the retire barrier."""
+    """Ingest front of a ShardedWindowArray: the staging batch, sealed
+    replicated onto the mesh, feeds hash-routed donated updates, plus the
+    donated shard-local ring rotation behind the retire barrier."""
     rot = lambda st: sharded_window_array.rotate(cfg, mesh, st, axis=axis, donate=True)
     return IngestPipeline(
         icfg, state, _sharded_window_update_fn(cfg, mesh, axis), rotate_fn=rot,
-        name=name,
+        name=name, mesh=mesh, k=sharded_window_array.num_sketches(state), axis=axis,
     )
 
 

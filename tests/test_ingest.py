@@ -334,6 +334,94 @@ def test_sharded_window_pipeline_rotation_bit_identical(mesh):
     _assert_window_equal(pipe.result(), ref)
 
 
+def test_sharded_pipeline_counts_each_batch_by_owning_shard(mesh):
+    """Each dispatched micro-batch's events, counted by the shard that owns
+    their row (``ingest/shard_rows`` while tracing, and the registry
+    counter), sum to the batch's live events; the batch is placed
+    replicated on the mesh. A single-device pipeline counts nothing."""
+    from repro.obs import metrics as obs_metrics
+    from repro.obs import trace as obs_trace
+
+    bsz, s = 64, len(mesh.devices.ravel())
+    trip = _elements(3 * bsz + 21, seed=71)
+    rows = K // s
+    obs_trace.configure(enabled=True)
+    obs_trace.clear()
+    try:
+        pipe = ingest.sharded_window_pipeline(
+            CFG, mesh, sharded_window_array.init(CFG, K, 3, mesh),
+            ingest.IngestConfig(batch_size=bsz), name="shard_count_test",
+        )
+        placed = []
+        real = pipe._update
+        pipe._update = lambda st, *batch: placed.append(batch[0].sharding) or real(st, *batch)
+        pipe.push(*trip)
+        pipe.rotate()
+        single = ingest.window_pipeline(
+            CFG, window_array.init(CFG, K, 3), ingest.IngestConfig(batch_size=bsz),
+            name="shard_count_single",
+        )
+        single.push(*trip)
+        single.result()
+        events = [e for e in obs_trace.events() if e["name"] == "ingest/shard_rows"]
+    finally:
+        obs_trace.configure(enabled=False)
+        obs_trace.clear()
+    want = [
+        np.bincount(b[0] // rows, minlength=s).tolist()
+        for b in _partition(*trip, bsz)
+    ]
+    assert [e["args"]["counts"] for e in events] == want  # one per batch, 4 of them
+    assert [sum(c) for c in want] == [bsz, bsz, bsz, 21]
+    assert all(p.is_fully_replicated and len(p.device_set) == s for p in placed)
+    total = np.sum(want, axis=0).tolist()
+    assert pipe.stats.shard_events == total
+    assert pipe.stats.snapshot(delta=True)["shard_events"] == total
+    assert pipe.stats.snapshot(delta=True)["shard_events"] == [0] * s
+    assert "shard_events" not in single.stats.snapshot()
+    fam = obs_metrics.default_registry().get("ingest_shard_events_total")
+    if obs_metrics.enabled():
+        got = {x.labels["shard"]: x.value for x in fam.series()
+               if x.labels["pipe"] == "shard_count_test"}
+        assert got == {str(i): c for i, c in enumerate(total)}
+    assert not [x for x in fam.series() if x.labels["pipe"] == "shard_count_single"]
+    pipe.stats.reset()
+    assert pipe.stats.shard_events == [0] * s
+
+
+@pytest.mark.parametrize("tracing", [False, True])
+def test_sharded_pipeline_shard_counts_without_registry(mesh, tracing):
+    """With the registry off, the per-shard counts fall back to the pipe's
+    own stats while tracing, and are not taken at all with the tracer off
+    too (telemetry only: the state is the same either way)."""
+    from repro.obs import metrics as obs_metrics
+    from repro.obs import trace as obs_trace
+
+    bsz, s = 64, len(mesh.devices.ravel())
+    trip = _elements(2 * bsz + 9, seed=73)
+    registry_was = obs_metrics.enabled()
+    obs_metrics.configure(enabled=False)
+    obs_trace.configure(enabled=tracing)
+    try:
+        pipe = ingest.sharded_window_pipeline(
+            CFG, mesh, sharded_window_array.init(CFG, K, 3, mesh),
+            ingest.IngestConfig(batch_size=bsz),
+        )
+        pipe.push(*trip)
+        got = pipe.result()
+    finally:
+        obs_metrics.configure(enabled=registry_was)
+        obs_trace.configure(enabled=False)
+        obs_trace.clear()
+    want = np.bincount(trip[0] // (K // s), minlength=s).tolist() if tracing else [0] * s
+    assert pipe.stats.shard_events == want
+    assert pipe.stats.snapshot()["shard_events"] == want
+    ref = sharded_window_array.init(CFG, K, 3, mesh)
+    for batch in _partition(*trip, bsz):
+        ref = sharded_window_array.update_batch(CFG, mesh, ref, *(jnp.asarray(x) for x in batch))
+    _assert_window_equal(got, ref)
+
+
 # ---------------------------------------------------------------------------
 # tenant front: routed ingest == synchronous route + update + rotate + evict
 # ---------------------------------------------------------------------------

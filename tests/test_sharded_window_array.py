@@ -31,6 +31,41 @@ def mesh():
     return make_sketch_mesh()  # 8 shards under scripts/test.sh
 
 
+def test_init_builds_each_shard_in_place_like_device_put_rows(mesh):
+    """``init`` through ``sharding.init_rows`` equals the single-host
+    ``window_array.init`` placed by ``device_put_rows``, leaf for leaf and
+    sharding for sharding, and each device holds only its own K/S rows."""
+    cfg, k, e = SketchConfig(m=16, b=8, seed=1), 64, 3
+    got = sharded_window_array.init(cfg, k, e, mesh)
+    ref = sharding.device_put_rows(
+        window_array.init(cfg, k, e), mesh, sharded_window_array.DIMS
+    )
+    s = sharding.num_shards(mesh)
+    dims = jax.tree.leaves(sharded_window_array.DIMS, is_leaf=lambda d: d is None)
+    for a, b, d in zip(jax.tree.leaves(got), jax.tree.leaves(ref), dims):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert a.dtype == b.dtype and a.sharding == b.sharding
+        shards = a.addressable_shards
+        assert len(shards) == s
+        for sh in shards:
+            want = a.shape if d is None else a.shape[:d] + (k // s,) + a.shape[d + 1:]
+            assert sh.data.shape == want
+
+
+def test_host_shard_counts_match_own_slots(mesh):
+    """``sharding.shard_counts`` (the host's per-shard event count) gives
+    each shard what ``own_slots`` masks it down to on the device, slots
+    outside [0, K) included (owned by no shard)."""
+    k, s = 64, sharding.num_shards(mesh)
+    rng = np.random.default_rng(5)
+    slots = np.concatenate([rng.integers(0, k, 200), [-3, -1, k, k + 5]]).astype(np.int32)
+    local = lambda sl: jnp.sum(sharding.own_slots(sl, k // s)[1]).reshape(1)
+    dev = sharding.shard_map_rows(local, mesh, in_dims=(None,), out_dims=0)(jnp.asarray(slots))
+    host = sharding.shard_counts(slots, k, s)
+    np.testing.assert_array_equal(host, np.asarray(dev))
+    assert host.sum() == 200
+
+
 def _stream(n, k, seed):
     rng = np.random.default_rng(seed)
     keys = jnp.asarray(rng.integers(0, k, n, dtype=np.int32))

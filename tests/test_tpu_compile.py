@@ -7,6 +7,9 @@ E = 4 epochs, micro-batches of 16384 and 65536 elements) with
 chip: what Mosaic or the TPU compiler refuses fails here, without a chip.
 The container-update tests compile the donated per-batch updates at the
 same widths and check from the compiled module that they stay in place.
+The four-chip tests compile the row-sharded window of the fleet deployment
+(K = 2^22 over the 2x2 host's four chips): its init builds each shard in
+place, and its pipeline update stays in place with no gathered state.
 Nothing runs, so nothing about results or times is checked.
 
 The topology is described inside a module fixture, never at import: only one
@@ -22,10 +25,11 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
-from repro.core import SketchConfig, dyn_array, window_array
+from repro.core import SketchConfig, dyn_array, sharded_window_array, sharding, window_array
 from repro.kernels import dyn_array_update, estimate, window_union
 from repro.sketchstream import ingest
 
@@ -34,7 +38,7 @@ R_MIN, TOP_BIN = -127, 254
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -48,10 +52,22 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
             compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The described host's four chips as a 1-D ``"sketch"`` mesh, with the
+    ``Auto`` axis type of ``launch.mesh.make_sketch_mesh``."""
+    return Mesh(np.array(topo.devices[:4]), (sharding.AXIS,), axis_types=(AxisType.Auto,))
 
 
 def _compile(fn, *shapes):
@@ -139,3 +155,63 @@ def test_container_update_stays_in_place_on_v5e(one_chip, which):
     plane_bytes = K * NB * 4
     assert compiled.memory_analysis().temp_size_in_bytes < plane_bytes // 16
     assert _plane_passes(compiled.as_text(), K * M) == []
+
+
+# The fleet window: 2^22 source rows, a quarter on each chip.
+K4 = 2**22
+FLEET_CFG = SketchConfig(m=M, b=8, seed=0)
+_FLEET_DIMS = jax.tree.leaves(sharded_window_array.DIMS, is_leaf=lambda d: d is None)
+
+
+def _fleet_init():
+    return window_array.init(FLEET_CFG, K4, E)
+
+
+def test_sharded_window_init_builds_each_shard_in_place_on_v5e(four_chips):
+    """``sharded_window_array.init``'s executable writes each chip's own
+    K/4 rows and nothing else: no temporaries, a quarter of the state per
+    device (the whole ring, 24.2e9 B, fits no single chip)."""
+    fill, _ = sharding.init_rows_jit(_fleet_init, four_chips, sharded_window_array.DIMS)
+    compiled = fill.lower().compile()
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes == 0
+    state = jax.tree.leaves(jax.eval_shape(_fleet_init))
+    total = sum(x.size * x.dtype.itemsize for x in state)
+    assert total > 16 * 2**30 > ma.output_size_in_bytes
+    assert ma.output_size_in_bytes < total // 4 + 2**20
+    for leaf, sh, d in zip(state, compiled.output_shardings, _FLEET_DIMS):
+        want = leaf.shape if d is None else leaf.shape[:d] + (K4 // 4,) + leaf.shape[d + 1:]
+        assert sh.shard_shape(leaf.shape) == want
+
+
+# Collectives that would move state between chips.
+_GATHERS = re.compile(r"\b(all-gather|all-to-all|collective-permute|reduce-scatter)[-a-z]*\(")
+# "%x = u32[] all-reduce(...)": the result shape of every all-reduce.
+_ALL_REDUCE = re.compile(r"= ([a-z][a-z0-9]*\[[\d,]*\])\S* all-reduce[-a-z]*\(")
+
+
+def test_sharded_window_pipeline_update_stays_in_place_on_v5e(four_chips):
+    """The donated sharded pipeline update, fed a replicated micro-batch,
+    aliases its whole state, keeps B-sized temporaries (the one-chip
+    update's), and crosses chips only by scalar all-reduces (the ticket):
+    no state is gathered or exchanged."""
+    b = 16384
+    state = jax.tree.leaves(jax.eval_shape(_fleet_init))
+    st = sharded_window_array.ShardedWindowArrayState(*[
+        jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=NamedSharding(four_chips, sharding.spec(d)))
+        for x, d in zip(state, _FLEET_DIMS)
+    ])
+    rep = NamedSharding(four_chips, PartitionSpec())
+    batch = [jax.ShapeDtypeStruct((b,), dt, sharding=rep)
+             for dt in (jnp.int32, jnp.uint32, jnp.float32, jnp.bool_)]
+    compiled = ingest._sharded_window_update_fn(FLEET_CFG, four_chips, sharding.AXIS).lower(
+        st, *batch).compile()
+    ma = compiled.memory_analysis()
+    per_chip = sum(x.size * x.dtype.itemsize for x in state) // 4
+    assert ma.alias_size_in_bytes >= per_chip - 2**10
+    assert ma.temp_size_in_bytes < b * NB * 4 * 4  # a few [B, 2^b] int32 blocks
+    text = compiled.as_text()
+    assert _GATHERS.findall(text) == []
+    reduced = _ALL_REDUCE.findall(text)
+    assert reduced and all(re.fullmatch(r"[a-z0-9]+\[\]", r) for r in reduced)
+    assert _plane_passes(text, K4 // 4 * M) == []
