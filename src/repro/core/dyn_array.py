@@ -42,7 +42,7 @@ import typing
 import jax
 import jax.numpy as jnp
 
-from . import estimation, estimators, hashing, key_directory, qsketch_dyn
+from . import estimation, hashing, key_directory, qsketch_dyn
 from .types import DynArrayState, DynState, SketchConfig
 
 
@@ -278,14 +278,56 @@ def update_batch(
     return _update_batch_jit(cfg, state, keys, ids, weights, mask)
 
 
-def rebuild_hists(cfg: SketchConfig, regs) -> jnp.ndarray:
-    """Per-key touched-register histograms from scratch (bin 0 pinned to 0).
+# Rows per block of the row-blocked count, sized so one block's [rows, m, 2^b]
+# compare stays near 32 MiB of int32.
+_COUNT_BLOCK_BYTES = 1 << 25
 
-    O(K·m) — the reference the incremental maintenance is tested against,
-    and the rebuild used by ``merge``.
+
+def _count_bins(cfg: SketchConfig, regs) -> jnp.ndarray:
+    """hists[k, v] = #{j : regs[k, j] == v + r_min}, bin 0 counting nothing
+    (it is compared against a value no int8 holds)."""
+    targets = jnp.arange(cfg.num_bins, dtype=jnp.int32) + cfg.r_min
+    targets = targets.at[0].set(jnp.iinfo(jnp.int32).max)
+    hit = regs.astype(jnp.int32)[..., None] == targets
+    return jnp.sum(hit.astype(jnp.int32), axis=-2)
+
+
+def _count_bins_blocked(cfg: SketchConfig, regs) -> jnp.ndarray:
+    """``_count_bins`` one fixed-size row block at a time (``lax.map``), the
+    last block padded with r_min rows (which count nothing)."""
+    k, m = regs.shape
+    rows = max(1, min(k, _COUNT_BLOCK_BYTES // (4 * m * cfg.num_bins)))
+    pad = -k % rows
+    blocks = jnp.pad(regs, ((0, pad), (0, 0)), constant_values=cfg.r_min)
+    hists = jax.lax.map(lambda b: _count_bins(cfg, b), blocks.reshape(-1, rows, m))
+    return hists.reshape(k + pad, cfg.num_bins)[:k]
+
+
+def rebuild_hists(cfg: SketchConfig, regs) -> jnp.ndarray:
+    """Per-key touched-register histograms from scratch (bin 0 pinned to 0):
+    ``int8[K, m]`` registers in, ``int32[K, 2^b]`` out.
+
+    A dense compare-and-count, hists[k, v] = #{j : regs[k, j] == v + r_min},
+    with no scatter and no sort: the same integers as the per-row scatter
+    ``estimators.histogram``, which lives on only in the tests as the oracle
+    this count is held to, bin for bin. Used by window rotation
+    (``window_array.rotate``, also per shard) and by both merges; the
+    incremental maintenance is tested against it.
+
+    Lowered for a TPU, the whole count is one reduction fusion: the compare
+    feeds the sum in registers, so no [K, m, 2^b] intermediate is written.
+    Other backends' compilers write a reduction's input out first, so there
+    the count runs in row blocks of ~32 MiB of compare. The choice is made
+    per lowering platform, so a TPU program compiled on a CPU host gets the
+    TPU form. The optimization barrier keeps consumers (the row sums of the
+    MLE's bin 0) from fusing a second copy of the count.
     """
-    hists = jax.vmap(lambda r: estimators.histogram(cfg, r))(regs)
-    return hists.at[:, 0].set(0)
+    hists = jax.lax.platform_dependent(
+        regs,
+        tpu=functools.partial(_count_bins, cfg),
+        default=functools.partial(_count_bins_blocked, cfg),
+    )
+    return jax.lax.optimization_barrier(hists)
 
 
 def estimate_all(state: DynArrayState) -> jnp.ndarray:

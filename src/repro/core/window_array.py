@@ -18,8 +18,13 @@ top). Semantics:
   union sub-state (2x the DynArray update cost, still independent of K and E).
 * ``rotate()`` closes the current epoch: O(1) ring bookkeeping (advance
   ``head``, reset the slot it lands on — evicting the oldest epoch once the
-  ring is full) plus a rebuild of the union cache from the surviving epochs
-  (O(E·K·m), paid at rotation cadence, amortized over an epoch of updates).
+  ring is full) plus a rebuild of the union cache from the surviving epochs,
+  paid at rotation cadence and amortized over an epoch of updates: the
+  register max (O(E·K·m)), the union histograms counted densely by
+  ``dyn_array.rebuild_hists`` (K·m int8 read, K·2^b int32 written: 128 MiB
+  and 1 GiB at K = 2^20, m = 128, b = 8, ~1.5 ms at a TPU v5e's HBM
+  bandwidth; the measured time is in PERF.md §6) and the MLE re-base of
+  every row.
 * ``estimate_window(w)`` answers "weighted cardinality over the last
   w <= E epochs": all-max union of the w epoch register planes — EXACT,
   the union of epoch streams is sketched by the register-wise max — read out

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import SketchConfig, dyn_array, key_directory, qsketch_dyn
+from repro.core import SketchConfig, dyn_array, estimators, key_directory, qsketch_dyn
 from repro.core.key_directory import DirectoryConfig
 from repro.core.types import DynArrayState
 from repro.kernels import ops
@@ -132,6 +132,45 @@ def test_incremental_hists_match_rebuild():
         np.testing.assert_array_equal(
             np.asarray(st.hists), np.asarray(dyn_array.rebuild_hists(cfg, st.regs))
         )
+
+
+def scatter_hists(cfg, regs):
+    """The per-row scatter-add histogram with bin 0 zeroed: the oracle the
+    dense rebuild is held to."""
+    return jax.vmap(lambda r: estimators.histogram(cfg, r))(regs).at[:, 0].set(0)
+
+
+# (m, K): "m128" is the deployment's register width; "m_below_128" and
+# "m_above_128" leave the register axis off the 128-lane tile; "one_row"
+# and "odd_rows" give K no power-of-two factor. K is not a multiple of the
+# row block in "m128" (256-row blocks) and "odd_rows" (2048-row blocks).
+REBUILD_CASES = {
+    "m128": (128, 1000),
+    "m_below_128": (40, 517),
+    "m_above_128": (257, 77),
+    "one_row": (64, 1),
+    "odd_rows": (16, 4099),
+}
+
+
+@pytest.mark.parametrize("m,k", list(REBUILD_CASES.values()), ids=list(REBUILD_CASES))
+def test_rebuild_hists_matches_scatter_oracle(m, k):
+    """The dense count == the per-row scatter, bin for bin, on random
+    registers over the whole range: every row holds r_min and r_max, the
+    second row is all r_min (an empty histogram) and the last all r_max.
+    Both forms are held to it: the row-blocked one this backend lowers and
+    the whole-array one a TPU lowers."""
+    cfg = SketchConfig(m=m, b=8, seed=m + k)
+    rng = np.random.default_rng(m * 3 + k)
+    regs = rng.integers(cfg.r_min, cfg.r_max + 1, (k, m)).astype(np.int8)
+    regs[:, 0], regs[:, -1] = cfg.r_min, cfg.r_max
+    regs[min(1, k - 1)], regs[-1] = cfg.r_min, cfg.r_max
+    got = np.asarray(jax.jit(lambda r: dyn_array.rebuild_hists(cfg, r))(regs))
+    want = np.asarray(scatter_hists(cfg, jnp.asarray(regs)))
+    assert got.shape == (k, cfg.num_bins) and got.dtype == np.int32
+    np.testing.assert_array_equal(got, want)
+    assert (got[:, 0] == 0).all() and got[-1, cfg.top_bin] == m
+    np.testing.assert_array_equal(np.asarray(dyn_array._count_bins(cfg, jnp.asarray(regs))), want)
 
 
 def test_estimate_all_is_anytime_read():

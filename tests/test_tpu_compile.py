@@ -215,3 +215,52 @@ def test_sharded_window_pipeline_update_stays_in_place_on_v5e(four_chips):
     reduced = _ALL_REDUCE.findall(text)
     assert reduced and all(re.fullmatch(r"[a-z0-9]+\[\]", r) for r in reduced)
     assert _plane_passes(text, K4 // 4 * M) == []
+
+
+# "%s = s32[268435456]{0:T(1024)} scatter(" or "%sort = (s32[134217728]{...},
+# s32[134217728]{...}) sort(": the first result shape of every scatter and
+# sort, tuple results included.
+_SCATTER_SORT = re.compile(r"= \(?[a-z][a-z0-9]*\[([\d,]*)\][^=\n]*? (scatter|sort)\(")
+# Temporaries of the donated rotation when its union histograms came from a
+# scatter-add of K·m ones into the flat plane and a sort of its indices:
+# 1,615,419,904 B on one chip at K = 2^20, and per chip of the fleet window.
+_SCATTER_ROTATE_TEMP_BYTES = 1_615_419_904
+
+
+def _assert_rotation_rebuilds_densely(compiled, rows):
+    """No scatter or sort of ``rows``·m elements or more in the optimized
+    module, and temporaries no larger than the scatter-built rotation's."""
+    big = [
+        (op, dims)
+        for dims, op in _SCATTER_SORT.findall(compiled.as_text())
+        if math.prod(int(d) for d in dims.split(",") if d) >= rows * M
+    ]
+    assert big == []
+    assert compiled.memory_analysis().temp_size_in_bytes <= _SCATTER_ROTATE_TEMP_BYTES
+
+
+def test_window_rotation_rebuilds_union_densely_on_v5e(one_chip):
+    """The donated rotation at the single-chip deployment's widths counts the
+    union histograms densely: neither the scatter into the ``int32[K·2^b]``
+    plane nor the sort of its K·m indices is compiled."""
+    cfg = SketchConfig(m=M, b=8, seed=0)
+    st = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(functools.partial(window_array.init, cfg, K, E)),
+    )
+    _assert_rotation_rebuilds_densely(window_array._rotate_donated.lower(cfg, st).compile(), K)
+
+
+def test_sharded_window_rotation_rebuilds_union_densely_on_v5e(four_chips):
+    """The fleet window's donated shard-local rotation (``window_array.rotate``
+    per shard under ``shard_map``) compiles the same dense count on each
+    chip's K/4 rows."""
+    state = jax.tree.leaves(jax.eval_shape(_fleet_init))
+    st = sharded_window_array.ShardedWindowArrayState(*[
+        jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=NamedSharding(four_chips, sharding.spec(d)))
+        for x, d in zip(state, _FLEET_DIMS)
+    ])
+    compiled = sharded_window_array._rotate_donated.lower(
+        FLEET_CFG, four_chips, sharding.AXIS, st
+    ).compile()
+    _assert_rotation_rebuilds_densely(compiled, K4 // 4)

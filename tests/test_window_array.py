@@ -11,8 +11,10 @@ import pytest
 
 from repro.core import SketchConfig, dyn_array, key_directory, window_array
 from repro.core.key_directory import DirectoryConfig
+from repro.core.types import WindowArrayState
 from repro.kernels import ops
 from repro.sketchstream import anomaly, monitor
+from test_dyn_array import scatter_hists
 
 # (batch, m, K, E) — ragged on purpose, matching the DynArray suite's habit.
 SHAPES = [
@@ -130,6 +132,32 @@ def test_union_cache_invariant_across_rotations():
             np.asarray(dyn_array.rebuild_hists(cfg, st.union_regs)),
         )
         st = window_array.rotate(cfg, st)
+
+
+@pytest.mark.parametrize("donate", [False, True], ids=["fused", "donated"])
+def test_rotation_matches_scatter_built_union(donate):
+    """Six rotations of an E = 4 ring (the head wraps and evicts), each after
+    a batch: every leaf, ``union_hists`` and ``union_chats`` included, is bit
+    for bit what the same rotation gives with the union histograms built by
+    the per-row scatter."""
+    cfg = SketchConfig(m=64, b=8, seed=21)
+    k, e = 37, 4
+    scatter_rotate = jax.jit(lambda s: window_array._rotate_impl(cfg, s))
+    st = ref = window_array.init(cfg, k, e)
+    for r in range(6):
+        keys, ids, w = _keyed_stream(400, k, seed=60 + r)
+        ref = window_array.update_batch(cfg, ref, keys, ids, w)
+        st = window_array.update_batch(cfg, st, keys, ids, w, donate=donate)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(dyn_array, "rebuild_hists", scatter_hists)
+            ref = scatter_rotate(ref)
+        st = window_array.rotate(cfg, st, donate=donate)
+        assert int(st.head) == (r + 1) % e
+        assert int(np.asarray(st.union_hists).sum()) > 0
+        for f in WindowArrayState._fields:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(st, f)), np.asarray(getattr(ref, f)), err_msg=f
+            )
 
 
 @pytest.mark.parametrize("batch,m,k,e", SHAPES)
